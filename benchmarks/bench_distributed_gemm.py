@@ -1,7 +1,9 @@
 """Multi-accelerator GEMM (the paper's Tesla S2050 section).
 
-Runs the three shard_map schedules on 8 forced-host devices in a
-subprocess (the main process keeps the 1-device world), measures
+Runs the three shard_map schedules in this process over every device
+JAX sees (one device degenerates each schedule to a local GEMM; for a
+multi-device run on a CPU host, start the interpreter with
+XLA_FLAGS=--xla_force_host_platform_device_count=8), measures
 wall-clock, and reports the ICI-byte model per schedule — the
 quantified form of the paper's 'matrices must be very large to amortise
 multi-GPU transfer' remark.
@@ -9,53 +11,32 @@ multi-GPU transfer' remark.
 
 from __future__ import annotations
 
-import subprocess
-import sys
-import textwrap
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from benchmarks.common import emit
-from repro.core.distributed import comm_model_bytes
-
-_SUB = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys, time
-sys.path.insert(0, "src")
-import numpy as np, jax, jax.numpy as jnp
-from repro.core.distributed import sharded_matmul
-from repro.launch.mesh import axis_kw
-
-mesh = jax.make_mesh((8,), ("model",), **axis_kw(1))
-rng = np.random.default_rng(0)
-m = k = n = 1024
-a = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
-b = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
-ref = a @ b
-for sched in ("ring", "column", "row"):
-    f = jax.jit(lambda x, y, s=sched: sharded_matmul(x, y, mesh, schedule=s))
-    out = f(a, b); jax.block_until_ready(out)
-    err = float(jnp.max(jnp.abs(out - ref)))
-    ts = []
-    for _ in range(3):
-        t0 = time.perf_counter(); jax.block_until_ready(f(a, b))
-        ts.append(time.perf_counter() - t0)
-    print(f"RESULT {sched} {sorted(ts)[1]:.6f} {err:.2e}")
-"""
+from repro.core.distributed import comm_model_bytes, sharded_matmul
+from repro.launch.mesh import auto_axes
+from repro.tuning.timing import time_jax
 
 
 def run() -> None:
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(_SUB)],
-                         capture_output=True, text=True, cwd="/root/repo",
-                         timeout=420)
-    assert out.returncode == 0, out.stderr[-2000:]
+    p = len(jax.devices())
+    mesh = jax.make_mesh((p,), ("model",), auto_axes(1))
+    rng = np.random.default_rng(0)
     m = k = n = 1024
-    for line in out.stdout.splitlines():
-        if not line.startswith("RESULT"):
-            continue
-        _, sched, t, err = line.split()
-        comm = comm_model_bytes(m, n, k, 8, 4, sched)
-        emit(f"distributed_gemm_{sched}_8dev_{m}", float(t),
-             f"maxerr={err};model_ici_bytes_per_dev={comm}")
+    a = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    b = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
+    ref = a @ b
+    for sched in ("ring", "column", "row"):
+        f = jax.jit(lambda x, y, s=sched: sharded_matmul(x, y, mesh,
+                                                         schedule=s))
+        err = float(jnp.max(jnp.abs(f(a, b) - ref)))
+        t = time_jax(f, a, b)
+        comm = comm_model_bytes(m, n, k, p, 4, sched)
+        emit(f"distributed_gemm_{sched}_{p}dev_{m}", t,
+             f"maxerr={err:.2e};model_ici_bytes_per_dev={comm}")
 
 
 if __name__ == "__main__":

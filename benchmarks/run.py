@@ -31,6 +31,7 @@ import traceback
 
 from repro.core import policy as policy_mod
 from repro.core.policy import LEGACY_BACKEND_NAMES, Policy
+from repro.launch import compile_cache
 
 from benchmarks import (bench_add, bench_arch_step, bench_distributed_gemm,
                         bench_flash_attention, bench_fused_epilogue,
@@ -66,6 +67,7 @@ def main() -> None:
                     help="sweep tile configs via repro.tuning and persist "
                          "winners to the tuning cache")
     args = ap.parse_args()
+    compile_cache.enable()
 
     # One typed Policy for the whole run: recorded in the BENCH json
     # (write_bench_json) so a result is reproducible from its file.

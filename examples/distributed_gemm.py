@@ -22,11 +22,11 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.core.distributed import comm_model_bytes, sharded_matmul  # noqa: E402
-from repro.launch.mesh import axis_kw  # noqa: E402
+from repro.launch.mesh import auto_axes  # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((8,), ("model",), **axis_kw(1))
+    mesh = jax.make_mesh((8,), ("model",), auto_axes(1))
     rng = np.random.default_rng(0)
     m = k = n = 1024
     a = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)

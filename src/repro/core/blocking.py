@@ -9,7 +9,7 @@ and MXU alignment. The constraints implemented here:
   * every tile dim is a multiple of the MXU edge (128) where possible,
     and at least the (sublane, lane) minimum for the dtype;
   * A-tile + B-tile (double-buffered) + f32 accumulator must fit a VMEM
-    budget (default: half of VMEM, leaving room for Mosaic);
+    budget (VMEM_FRACTION of VMEM, leaving room for Mosaic);
   * maximise arithmetic intensity  AI = 2*bm*bn*bk / (bm*bk + bk*bn + bm*bn)
     which is what makes the kernel compute-bound (paper claim C2).
 
@@ -24,6 +24,15 @@ import dataclasses
 import math
 
 from repro.core import hw
+
+#: Share of a chip's VMEM one kernel's working set may take. The tile
+#: choosers size against it, and the kernels compile under a scoped VMEM
+#: limit of exactly this many bytes (kernels.compiler_params).
+VMEM_FRACTION = 0.5
+
+
+def vmem_budget(chip: hw.ChipSpec = hw.DEFAULT_CHIP) -> int:
+    return int(chip.vmem_bytes * VMEM_FRACTION)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,7 +127,7 @@ def choose_ssd_config(
     n: int,
     itemsize: int = 4,
     chip: hw.ChipSpec = hw.DEFAULT_CHIP,
-    vmem_fraction: float = 0.5,
+    vmem_fraction: float = VMEM_FRACTION,
 ) -> SSDBlockConfig:
     """Default (q, bp) for the SSD kernel: run at the model's configured
     chunk with the full head dim, halving the time tile while the
@@ -190,7 +199,7 @@ def choose_block_config(
     k: int,
     itemsize: int = 2,
     chip: hw.ChipSpec = hw.DEFAULT_CHIP,
-    vmem_fraction: float = 0.5,
+    vmem_fraction: float = VMEM_FRACTION,
     n_rhs: int = 1,
 ) -> BlockConfig:
     """Pick (bm, bn, bk) for an (m, k) x (k, n) GEMM.

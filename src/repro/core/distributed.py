@@ -24,8 +24,7 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import gemm as _gemm
 
@@ -57,10 +56,7 @@ def ring_matmul(x, w, *, axis: str, backend: str | None = None):
     pass it to the next ring neighbour. P-1 permutes hide behind P local
     GEMMs of shape (M_local, K/p, N).
     """
-    # jax >= 0.5 has lax.axis_size; the psum-of-1 idiom is the portable
-    # spelling (constant-folded to a static int for named axes).
-    p = (jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size")
-         else jax.lax.psum(1, axis))
+    p = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     kb = w.shape[0]          # local K block
     n = w.shape[1]
@@ -76,8 +72,6 @@ def ring_matmul(x, w, *, axis: str, backend: str | None = None):
         return acc, w_t
 
     acc0 = jnp.zeros(x.shape[:-1] + (n,), dtype=x.dtype)
-    if hasattr(jax.lax, "pvary"):  # jax >= 0.5 varying-manual-axes type
-        acc0 = jax.lax.pvary(acc0, (axis,))  # match the loop body's vma
     acc, _ = jax.lax.fori_loop(0, p, body, (acc0, w))
     return acc
 
@@ -95,30 +89,36 @@ def sharded_matmul(
 
     A (M, K) is sharded on M over `axis` for ring/column, on K for row;
     B (K, N) is sharded to match the schedule. Returns the full product.
+    The per-device GEMM may be a Pallas kernel, whose output type
+    carries no varying-mesh-axes information, so the shard_maps run
+    with check_vma=False.
     """
     if schedule == "ring":
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(ring_matmul, axis=axis, backend=backend),
             mesh=mesh,
             in_specs=(P(axis, None), P(axis, None)),
             out_specs=P(axis, None),
+            check_vma=False,
         )
         return fn(a, b)
     if schedule == "column":
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(column_parallel, axis=axis, backend=backend),
             mesh=mesh,
             in_specs=(P(axis, None), P(None, None)),
             out_specs=P(axis, None),
+            check_vma=False,
         )
         return fn(a, b)
     if schedule == "row":
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(row_parallel, axis=axis, backend=backend,
                               scatter=False),
             mesh=mesh,
             in_specs=(P(None, axis), P(axis, None)),
             out_specs=P(None, None),
+            check_vma=False,
         )
         return fn(a, b)
     raise ValueError(f"unknown schedule {schedule!r}")

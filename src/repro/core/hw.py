@@ -1,11 +1,16 @@
 """Hardware model for the roofline / blocking analysis.
 
-The container is CPU-only; TPU v5e is the *target*. All sizing decisions
-(the paper's shared-memory-budget argument redone for VMEM) and all
-roofline terms are computed against this model.
+TPU v5e is the chip this repository runs on. All sizing decisions (the
+paper's shared-memory-budget argument redone for VMEM) and all roofline
+terms are computed against these specs. Measured devices are looked up
+by the `device_kind` JAX reports (`chip_for`); a device that is not in
+that table is an error, never a default. The paper's Tesla C2050/C1060
+stay as named targets for its modeled byte tables only.
 
-Numbers fixed by the task spec: 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI.
+TPU v5e peaks (Google Cloud documentation, "TPU v5e",
+cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16 and 393 TOP/s int8 per
+chip, 16 GB HBM at 819 GB/s, 1,600 Gbit/s inter-chip interconnect
+(4 links, so ~50 GB/s per link per direction); 128 MiB VMEM per core.
 """
 
 from __future__ import annotations
@@ -85,6 +90,21 @@ TESLA_C1060 = ChipSpec(
 )
 
 DEFAULT_CHIP = TPU_V5E
+
+#: Measured chips keyed by the `device_kind` string JAX reports.
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_for(device_kind: str) -> ChipSpec:
+    """The spec (peaks, VMEM) of a device JAX reports; raises for a
+    device kind with no table entry, so no measurement is ever scored
+    against another chip's peaks."""
+    try:
+        return DEVICE_KINDS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table entry for device kind {device_kind!r}; "
+            f"known: {sorted(DEVICE_KINDS)}") from None
 
 #: Name -> spec registry (core.policy parses `chip=` policy fields
 #: against this, so REPRO_POLICY can select any modeled chip).

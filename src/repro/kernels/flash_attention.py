@@ -19,13 +19,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from repro.kernels import compiler_params, row_to_column
 
 _NEG_INF = -1e30
 # logsumexp sentinel for a fully-masked row (inactive decode slot): the
@@ -35,8 +31,15 @@ _LSE_EMPTY = 1e30
 _LANES = 128
 
 
+def _row_offsets(offset, rows: int) -> jnp.ndarray:
+    """Scalar or per-row offset -> the (rows,) int32 scalar-prefetch
+    operand the kernels index by grid row."""
+    return jnp.broadcast_to(
+        jnp.asarray(offset, jnp.int32).reshape(-1), (rows,))
+
+
 def _flash_kernel(
-    q_ref, k_ref, v_ref, qo_ref, o_ref, *rest,
+    qo_ref, q_ref, k_ref, v_ref, o_ref, *rest,
     n_kv: int, bq: int, bk: int, scale: float,
     causal: bool, window: int | None, save_lse: bool,
 ):
@@ -52,10 +55,11 @@ def _flash_kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # q_offset streams in as data (one scalar per B*H row) so a single
-    # compiled kernel serves every decode depth — and, with a per-row
-    # vector, a continuous batch of requests at heterogeneous depths.
-    q_start = pl.program_id(1) * bq + qo_ref[0, 0]
+    # q_offset is a scalar-prefetch operand (one int per B*H row, in
+    # SMEM before the grid runs) so a single compiled kernel serves every
+    # decode depth — and, with a per-row vector, a continuous batch of
+    # requests at heterogeneous depths.
+    q_start = pl.program_id(1) * bq + qo_ref[pl.program_id(0)]
     k_start = kv_i * bk
 
     # Block-level skip: entirely above the causal diagonal or entirely
@@ -89,7 +93,10 @@ def _flash_kernel(
         s_max = jnp.max(s, axis=1, keepdims=True)         # (bq, 1)
         m_new = jnp.maximum(m_prev, s_max)                # broadcast
         alpha = jnp.exp(m_prev - m_new)                   # (bq, LANES)
-        p = jnp.exp(s - m_new[:, :1])                     # (bq, bk)
+        # masked keys weigh exactly 0 even while a row has seen no valid
+        # key (m = -1e30, where exp(s - m) would be 1): a row that never
+        # does (non-causal window) keeps l = 0 and flushes to 0.
+        p = jnp.where(mask, jnp.exp(s - m_new[:, :1]), 0.0)   # (bq, bk)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(
             p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
@@ -104,10 +111,14 @@ def _flash_kernel(
         o_ref[0] = (acc_ref[...] / lsafe).astype(o_ref.dtype)
         if save_lse:
             # lse = m + log(l) in the scaled-logit units the backward
-            # recomputes S in; empty rows get the +inf sentinel.
-            lse = jnp.where(l > 0.0,
-                            m_ref[:, :1] + jnp.log(lsafe), _LSE_EMPTY)
-            lse_ref[0] = lse[:, 0]
+            # recomputes S in; empty rows get the +inf sentinel. m and l
+            # are lane-replicated, so the (bq, LANES) tile transposes
+            # into the lane-dense (1, bq) row the lse output stores.
+            lf = l_ref[...]
+            lse = jnp.where(lf > 0.0,
+                            m_ref[...] + jnp.log(jnp.where(lf == 0.0, 1.0, lf)),
+                            _LSE_EMPTY)
+            lse_ref[0] = lse.T[:1]
 
 
 def flash_attention(
@@ -142,57 +153,49 @@ def flash_attention(
     assert tq % bq == 0 and tk % bk == 0, (tq, tk, bq, bk)
     n_kv = tk // bk
 
-    # Per-row query offsets ride along as a (bh, 1) int32 operand; a
-    # scalar broadcasts to all rows (2-D because TPU scalars live in
-    # SMEM as (1, 1) blocks).
-    qo = jnp.broadcast_to(
-        jnp.asarray(q_offset, jnp.int32).reshape(-1, 1), (bh, 1))
-
     kernel = functools.partial(
         _flash_kernel, n_kv=n_kv, bq=bq, bk=bk, scale=scale,
         causal=causal, window=window, save_lse=return_lse)
 
-    if _HAS_PLTPU:
-        scratch = [
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-        ]
-    else:  # pragma: no cover
-        scratch = []
-
-    params = {}
-    if _HAS_PLTPU and not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
-
-    o_spec = pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0))
+    o_spec = pl.BlockSpec((1, bq, d), lambda h, i, j, qo: (h, i, 0))
     o_shape = jax.ShapeDtypeStruct((bh, tq, d), q.dtype)
     out_specs = o_spec
     out_shape = o_shape
     if return_lse:
-        out_specs = [o_spec, pl.BlockSpec((1, bq), lambda h, i, j: (h, i))]
-        out_shape = [o_shape, jax.ShapeDtypeStruct((bh, tq), jnp.float32)]
+        # lse rows are stored (bh, 1, tq): a (1, bq) block is lane-dense
+        # and satisfies the TPU (8, 128) block rule on its last two dims.
+        out_specs = [o_spec, pl.BlockSpec((1, 1, bq),
+                                          lambda h, i, j, qo: (h, 0, i))]
+        out_shape = [o_shape,
+                     jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32)]
 
-    qo_spec_kw = {"memory_space": pltpu.SMEM} if _HAS_PLTPU else {}
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(bh, tq // bq, n_kv),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda h, i, j, g=group: (h // g, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda h, i, j, g=group: (h // g, j, 0)),
-            pl.BlockSpec((1, 1), lambda h, i, j: (h, 0), **qo_spec_kw),
+            pl.BlockSpec((1, bq, d), lambda h, i, j, qo: (h, i, 0)),
+            pl.BlockSpec((1, bk, d),
+                         lambda h, i, j, qo, g=group: (h // g, j, 0)),
+            pl.BlockSpec((1, bk, d),
+                         lambda h, i, j, qo, g=group: (h // g, j, 0)),
         ],
         out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+            pltpu.VMEM((bq, _LANES), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=out_shape,
-        scratch_shapes=scratch,
         interpret=interpret,
-        **params,
-    )(q, k, v, qo)
+        name="flash_fwd",
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
+    )(_row_offsets(q_offset, bh), q, k, v)
     if return_lse:
-        return out[0], out[1]
+        return out[0], out[1].reshape(bh, tq)
     return out
 
 
@@ -221,8 +224,8 @@ def _bwd_tiles(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)                      # (bk, d)
     v = v_ref[0].astype(jnp.float32)                      # (bk, d)
     do = do_ref[0].astype(jnp.float32)                    # (bq, d)
-    lse = lse_ref[0][:, None]                             # (bq, 1)
-    delta = delta_ref[0][:, None]                         # (bq, 1)
+    lse = row_to_column(lse_ref[0])                       # (bq, 1)
+    delta = row_to_column(delta_ref[0])                   # (bq, 1)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
@@ -243,7 +246,7 @@ def _bwd_tiles(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qo_ref,
+    qo_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref, dk_acc, dv_acc,
     *, n_q: int, bq: int, bk: int, scale: float,
     causal: bool, window: int | None,
@@ -255,7 +258,7 @@ def _flash_bwd_dkv_kernel(
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_start = q_i * bq + qo_ref[0, 0]
+    q_start = q_i * bq + qo_ref[pl.program_id(0)]
     k_start = pl.program_id(1) * bk
     run = True
     if causal:
@@ -282,7 +285,7 @@ def _flash_bwd_dkv_kernel(
 
 
 def _flash_bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qo_ref,
+    qo_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dq_acc,
     *, n_kv: int, bq: int, bk: int, scale: float,
     causal: bool, window: int | None,
@@ -293,7 +296,7 @@ def _flash_bwd_dq_kernel(
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    q_start = pl.program_id(1) * bq + qo_ref[0, 0]
+    q_start = pl.program_id(1) * bq + qo_ref[pl.program_id(0)]
     k_start = kv_i * bk
     run = True
     if causal:
@@ -351,68 +354,68 @@ def flash_attention_bwd(
     assert tq % bq == 0 and tk % bk == 0, (tq, tk, bq, bk)
     n_q, n_kv = tq // bq, tk // bk
 
-    qo = jnp.broadcast_to(
-        jnp.asarray(q_offset, jnp.int32).reshape(-1, 1), (bh, 1))
-    lse = lse.astype(jnp.float32)
-    # D = rowsum(dO * O): one cheap XLA reduction instead of a third
-    # sweep — (bh, tq) f32 streams into both kernels like lse does.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    qo = _row_offsets(q_offset, bh)
+    # lse and D = rowsum(dO * O) (one cheap XLA reduction instead of a
+    # third sweep) stream into both kernels as lane-dense (bh, 1, tq) f32
+    # rows, the layout the forward stores lse in.
+    lse = lse.astype(jnp.float32).reshape(bh, 1, tq)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1).reshape(bh, 1, tq)
+    params = compiler_params("parallel", "parallel", "arbitrary")
 
-    qd_spec = pl.BlockSpec((1, bq, d), lambda h, j, i: (h, i, 0))
-    row_spec = pl.BlockSpec((1, bq), lambda h, j, i: (h, i))
-    kv_spec = pl.BlockSpec((1, bk, d), lambda h, j, i, g=group: (h // g, j, 0))
-    qo_spec_kw = {"memory_space": pltpu.SMEM} if _HAS_PLTPU else {}
-    qo_spec = pl.BlockSpec((1, 1), lambda h, j, i: (h, 0), **qo_spec_kw)
-
-    params = {}
-    if _HAS_PLTPU and not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
-
+    qd_spec = pl.BlockSpec((1, bq, d), lambda h, j, i, qo: (h, i, 0))
+    row_spec = pl.BlockSpec((1, 1, bq), lambda h, j, i, qo: (h, 0, i))
+    kv_spec = pl.BlockSpec((1, bk, d),
+                           lambda h, j, i, qo, g=group: (h // g, j, 0))
     dkv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, n_q=n_q, bq=bq, bk=bk, scale=scale,
             causal=causal, window=window),
-        grid=(bh, n_kv, n_q),
-        in_specs=[qd_spec, kv_spec, kv_spec, qd_spec, row_spec, row_spec,
-                  qo_spec],
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda h, j, i: (h, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda h, j, i: (h, j, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, n_kv, n_q),
+            in_specs=[qd_spec, kv_spec, kv_spec, qd_spec, row_spec,
+                      row_spec],
+            out_specs=[
+                pl.BlockSpec((1, bk, d), lambda h, j, i, qo: (h, j, 0)),
+                pl.BlockSpec((1, bk, d), lambda h, j, i, qo: (h, j, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)] * 2,
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk, d), jnp.float32),
             jax.ShapeDtypeStruct((bh, tk, d), jnp.float32),
         ],
-        scratch_shapes=([pltpu.VMEM((bk, d), jnp.float32)] * 2
-                        if _HAS_PLTPU else []),
         interpret=interpret,
-        **params,
-    )(q, k, v, do, lse, delta, qo)
+        name="flash_bwd_dkv",
+        compiler_params=params,
+    )(qo, q, k, v, do, lse, delta)
     dk, dv = dkv
 
     # Sweep 2 swaps the roles: grid (bh, n_q, n_kv), so the same specs
     # serve with (j, i) now meaning (q-block, kv-block).
-    qd_spec2 = pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0))
-    row_spec2 = pl.BlockSpec((1, bq), lambda h, i, j: (h, i))
+    qd_spec2 = pl.BlockSpec((1, bq, d), lambda h, i, j, qo: (h, i, 0))
+    row_spec2 = pl.BlockSpec((1, 1, bq), lambda h, i, j, qo: (h, 0, i))
     kv_spec2 = pl.BlockSpec((1, bk, d),
-                            lambda h, i, j, g=group: (h // g, j, 0))
-    qo_spec2 = pl.BlockSpec((1, 1), lambda h, i, j: (h, 0), **qo_spec_kw)
+                            lambda h, i, j, qo, g=group: (h // g, j, 0))
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, n_kv=n_kv, bq=bq, bk=bk, scale=scale,
             causal=causal, window=window),
-        grid=(bh, n_q, n_kv),
-        in_specs=[qd_spec2, kv_spec2, kv_spec2, qd_spec2, row_spec2,
-                  row_spec2, qo_spec2],
-        out_specs=pl.BlockSpec((1, bq, d), lambda h, i, j: (h, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, n_q, n_kv),
+            in_specs=[qd_spec2, kv_spec2, kv_spec2, qd_spec2, row_spec2,
+                      row_spec2],
+            out_specs=pl.BlockSpec((1, bq, d),
+                                   lambda h, i, j, qo: (h, i, 0)),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), jnp.float32),
-        scratch_shapes=([pltpu.VMEM((bq, d), jnp.float32)]
-                        if _HAS_PLTPU else []),
         interpret=interpret,
-        **params,
-    )(q, k, v, do, lse, delta, qo)
+        name="flash_bwd_dq",
+        compiler_params=params,
+    )(qo, q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
@@ -421,7 +424,7 @@ def flash_attention_bwd(
 # ----------------------------------------------------------------------
 
 def _flash_decode_kernel(
-    q_ref, k_ref, v_ref, pos_ref, o_ref, acc_ref, m_ref, l_ref,
+    pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     *, n_kv: int, bk: int, scale: float, window: int | None,
 ):
     kv_i = pl.program_id(1)
@@ -432,7 +435,7 @@ def _flash_decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0)]       # scalar-prefetch, (B*H,) SMEM
     k_start = kv_i * bk
 
     # THE decode win: only blocks intersecting the valid prefix
@@ -507,49 +510,39 @@ def flash_decode(
     assert tk % bk == 0, (tk, bk)
     n_kv = tk // bk
 
-    pos_op = jnp.broadcast_to(
-        jnp.asarray(pos, jnp.int32).reshape(-1, 1), (bh, 1))
-
-    if _HAS_PLTPU:
-        scratch = [
-            pltpu.VMEM((1, d), jnp.float32),
-            pltpu.VMEM((1, _LANES), jnp.float32),
-            pltpu.VMEM((1, _LANES), jnp.float32),
-        ]
-    else:  # pragma: no cover
-        scratch = []
-
-    params = {}
-    if _HAS_PLTPU and not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        )
-
-    pos_spec_kw = {"memory_space": pltpu.SMEM} if _HAS_PLTPU else {}
     return pl.pallas_call(
         functools.partial(
             _flash_decode_kernel, n_kv=n_kv, bk=bk, scale=scale,
             window=window),
-        grid=(bh, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda h, j: (h, 0, 0)),
-            pl.BlockSpec((1, bk, d), lambda h, j, g=group: (h // g, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda h, j, g=group: (h // g, j, 0)),
-            pl.BlockSpec((1, 1), lambda h, j: (h, 0), **pos_spec_kw),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda h, j: (h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, n_kv),
+            in_specs=[
+                pl.BlockSpec((1, 1, d), lambda h, j, p: (h, 0, 0)),
+                pl.BlockSpec((1, bk, d),
+                             lambda h, j, p, g=group: (h // g, j, 0)),
+                pl.BlockSpec((1, bk, d),
+                             lambda h, j, p, g=group: (h // g, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, d), lambda h, j, p: (h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((1, d), jnp.float32),
+                pltpu.VMEM((1, _LANES), jnp.float32),
+                pltpu.VMEM((1, _LANES), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-        scratch_shapes=scratch,
         interpret=interpret,
-        **params,
-    )(q, k, v, pos_op)
+        name="flash_decode",
+        compiler_params=compiler_params("parallel", "arbitrary"),
+    )(_row_offsets(pos, bh), q, k, v)
 
 
 # ----------------------------------------------------------------------
 # Paged decode kernel (K/V gathered through a slot page table)
 # ----------------------------------------------------------------------
 #
-# The serving cache is a pool of (page_size, Hkv, d) pages shared across
+# The serving cache is a pool of (Hkv, page_size, d) pages shared across
 # slots (serving.kv_pool); each slot owns a page-table row mapping its
 # logical pages onto pool indices. The table and the per-slot pos vector
 # ride in as SCALAR-PREFETCH operands — they land in SMEM before the
@@ -561,6 +554,8 @@ def flash_decode(
 # matter where its pages sit in the pool. int8 pools dequantize on the
 # f32 accumulator inside the kernel: the per-(position, head) scales
 # stream as (P, Hkv, page_size) planes sliced by the same index map.
+# Head-major pages make a K/V block (bk, d) and a scale block (1, bk):
+# tiles whose minor dims the TPU's (8, 128) rule accepts.
 
 def _flash_decode_paged_kernel(
     table_ref, pos_ref,            # scalar-prefetch: (B, pp), (B,) SMEM
@@ -596,11 +591,11 @@ def _flash_decode_paged_kernel(
     @pl.when(run)
     def _body():
         q = q_ref[0].astype(jnp.float32) * scale          # (1, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)         # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)               # (bk, d)
+        v = v_ref[0, 0].astype(jnp.float32)
         if quant:
-            k = k * ks_ref[0, 0][:, None]                 # dequant on f32
-            v = v * vs_ref[0, 0][:, None]
+            k = k * row_to_column(ks_ref[0])              # dequant on f32
+            v = v * row_to_column(vs_ref[0])
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)           # (1, bk)
@@ -630,8 +625,8 @@ def _flash_decode_paged_kernel(
 
 def flash_decode_paged(
     q: jnp.ndarray,           # [B, H, D]  one new token per slot
-    kp: jnp.ndarray,          # [P, page_size, Hkv, D]  K page pool
-    vp: jnp.ndarray,          # [P, page_size, Hkv, D]  V page pool
+    kp: jnp.ndarray,          # [P, Hkv, page_size, D]  K page pool
+    vp: jnp.ndarray,          # [P, Hkv, page_size, D]  V page pool
     table: jnp.ndarray,       # [B, pages_per_slot] int32; -1 = unmapped
     *,
     group: int = 1,           # H // Hkv
@@ -652,14 +647,10 @@ def flash_decode_paged(
     f32 accumulator, so HBM streams one byte per element. Returns
     [B, H, D]; rows with pos < 0 produce finite garbage the caller
     discards (same contract as flash_decode)."""
-    if not _HAS_PLTPU:  # pragma: no cover
-        raise NotImplementedError(
-            "flash_decode_paged needs pallas TPU scalar prefetch "
-            "(jax.experimental.pallas.tpu unavailable)")
     if block is not None:
         bk = block.bk
     b, h, d = q.shape
-    n_pages, ps, hkv, dk_ = kp.shape
+    n_pages, hkv, ps, dk_ = kp.shape
     assert d == dk_ and vp.shape == kp.shape, (q.shape, kp.shape, vp.shape)
     assert h == hkv * group, (h, hkv, group)
     pp = table.shape[1]
@@ -675,38 +666,36 @@ def flash_decode_paged(
     n_steps = pp * spp
     scale = scale if scale is not None else d ** -0.5
 
-    pos_op = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     table = jnp.asarray(table, jnp.int32)
 
     def page_map(bi, hi, j, t, p, g=group, s=spp):
         # -1 (unmapped) clamps to pool page 0; such steps never run.
-        return (jnp.maximum(t[bi, j // s], 0), j % s, hi // g, 0)
+        return (jnp.maximum(t[bi, j // s], 0), hi // g, j % s, 0)
 
-    def scale_map(bi, hi, j, t, p, g=group, s=spp):
-        return (jnp.maximum(t[bi, j // s], 0), hi // g, j % s)
+    def scale_map(bi, hi, j, t, p, g=group, s=spp, n=hkv):
+        return (jnp.maximum(t[bi, j // s], 0) * n + hi // g, 0, j % s)
+
+    # q and o travel as (B*H, 1, d) rows: a (1, d) block per head
+    def row_map(bi, hi, j, t, p, n=h):
+        return (bi * n + hi, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, d), lambda bi, hi, j, t, p: (bi, hi, 0)),
-        pl.BlockSpec((1, bk, 1, d), page_map),
-        pl.BlockSpec((1, bk, 1, d), page_map),
+        pl.BlockSpec((1, 1, d), row_map),
+        pl.BlockSpec((1, 1, bk, d), page_map),
+        pl.BlockSpec((1, 1, bk, d), page_map),
     ]
-    operands = [q, kp, vp]
+    operands = [q.reshape(b * h, 1, d), kp, vp]
     if quant:
+        # one (page, head) scale row per leading index: a (1, bk) block
         in_specs += [pl.BlockSpec((1, 1, bk), scale_map)] * 2
-        operands += [ks, vs]
-
-    params = {}
-    if not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
+        operands += [ks.reshape(n_pages * hkv, 1, ps),
+                     vs.reshape(n_pages * hkv, 1, ps)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, h, n_steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, d), lambda bi, hi, j, t, p:
-                               (bi, hi, 0)),
+        out_specs=pl.BlockSpec((1, 1, d), row_map),
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),
             pltpu.VMEM((1, _LANES), jnp.float32),
@@ -718,7 +707,8 @@ def flash_decode_paged(
             _flash_decode_paged_kernel, n_steps=n_steps, bk=bk,
             scale=scale, window=window, quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), q.dtype),
         interpret=interpret,
-        **params,
-    )(table, pos_op, *operands)
+        name="flash_decode_paged",
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
+    )(table, _row_offsets(pos, b), *operands).reshape(b, h, d)

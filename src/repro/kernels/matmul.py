@@ -54,13 +54,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific pieces; interpret mode works without a TPU.
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from repro.kernels import compiler_params, mxu_precision
 
 EPILOGUES = ("none", "bias", "bias_gelu", "bias_silu", "residual")
 
@@ -91,7 +87,8 @@ def _matmul_kernel(*refs, n_k: int, out_dtype, epilogue: str = "none"):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=acc_ref.dtype
+        a_ref[...], b_ref[...], preferred_element_type=acc_ref.dtype,
+        precision=mxu_precision(a_ref.dtype),
     )
 
     @pl.when(k == n_k - 1)
@@ -120,6 +117,7 @@ def _matmul_q_kernel(*refs, n_k: int, out_dtype, epilogue: str = "none"):
     acc_ref[...] += jnp.dot(
         a_ref[...], b_ref[...].astype(a_ref.dtype),
         preferred_element_type=acc_ref.dtype,
+        precision=mxu_precision(a_ref.dtype),
     )
 
     @pl.when(k == n_k - 1)
@@ -142,9 +140,10 @@ def _gated_matmul_kernel(a_ref, g_ref, u_ref, o_ref, accg_ref, accu_ref,
         accu_ref[...] = jnp.zeros_like(accu_ref)
 
     a = a_ref[...]
-    accg_ref[...] += jnp.dot(a, g_ref[...],
+    prec = mxu_precision(a.dtype)
+    accg_ref[...] += jnp.dot(a, g_ref[...], precision=prec,
                              preferred_element_type=accg_ref.dtype)
-    accu_ref[...] += jnp.dot(a, u_ref[...],
+    accu_ref[...] += jnp.dot(a, u_ref[...], precision=prec,
                              preferred_element_type=accu_ref.dtype)
 
     @pl.when(k == n_k - 1)
@@ -171,19 +170,10 @@ def _clamp_block(bm: int, bn: int, bk: int, m: int, n: int, ka: int):
     return bm_c, bn_c, bk_c
 
 
-def _tile_params(bm: int, bn: int, acc_dtype, interpret: bool,
-                 n_acc: int = 1):
-    if _HAS_PLTPU:
-        scratch = [pltpu.VMEM((bm, bn), acc_dtype) for _ in range(n_acc)]
-    else:  # pragma: no cover
-        scratch = [pl.MemorySpace.ANY((bm, bn), acc_dtype)
-                   for _ in range(n_acc)]
-    params = {}
-    if _HAS_PLTPU and not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        )
-    return scratch, params
+def _tile_params(bm: int, bn: int, acc_dtype, n_acc: int = 1):
+    scratch = [pltpu.VMEM((bm, bn), acc_dtype) for _ in range(n_acc)]
+    return scratch, {"compiler_params": compiler_params(
+        "parallel", "parallel", "arbitrary")}
 
 
 def matmul_tiled(
@@ -240,7 +230,7 @@ def matmul_tiled(
             in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
         operands.append(e)
 
-    scratch, params = _tile_params(bm, bn, acc_dtype, interpret)
+    scratch, params = _tile_params(bm, bn, acc_dtype)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -249,6 +239,7 @@ def matmul_tiled(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="matmul_tiled",
         **params,
     )(*operands)
 
@@ -311,7 +302,7 @@ def matmul_q_tiled(
             in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
         operands.append(e)
 
-    scratch, params = _tile_params(bm, bn, acc_dtype, interpret)
+    scratch, params = _tile_params(bm, bn, acc_dtype)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -320,6 +311,7 @@ def matmul_q_tiled(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="matmul_q_tiled",
         **params,
     )(*operands)
 
@@ -356,7 +348,7 @@ def gated_matmul_tiled(
     grid = (m // bm, n // bn, n_k)
     kernel = functools.partial(_gated_matmul_kernel, n_k=n_k,
                                out_dtype=out_dtype)
-    scratch, params = _tile_params(bm, bn, acc_dtype, interpret, n_acc=2)
+    scratch, params = _tile_params(bm, bn, acc_dtype, n_acc=2)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -369,5 +361,6 @@ def gated_matmul_tiled(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="gated_matmul_tiled",
         **params,
     )(a, w_gate, w_up)

@@ -27,11 +27,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import mxu_precision
+
 
 def _naive_kernel(a_ref, b_ref, o_ref, *, out_dtype):
     acc_dtype = jnp.float64 if a_ref.dtype == jnp.float64 else jnp.float32
     o_ref[...] = jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=acc_dtype
+        a_ref[...], b_ref[...], preferred_element_type=acc_dtype,
+        precision=mxu_precision(a_ref.dtype),
     ).astype(out_dtype)
 
 
@@ -63,4 +66,5 @@ def matmul_naive(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=interpret,
+        name="matmul_naive",
     )(a, b)

@@ -635,8 +635,7 @@ def _flash_decode_paged_xla(q, kp, vp, table, *, policy, pos, window,
 def _flash_decode_paged_pallas(q, kp, vp, table, *, policy, pos, window,
                                ks, vs, bk, block):
     b_, tq, h, d = q.shape
-    ps = kp.shape[1]
-    hkv = kp.shape[2]
+    hkv, ps = kp.shape[1], kp.shape[2]
     if block is None and policy.autotune == "cached":
         block = _tcache.get_cache().get_flash_decode_paged(
             ps, d, q.dtype, policy)
@@ -650,8 +649,8 @@ def _flash_decode_paged_pallas(q, kp, vp, table, *, policy, pos, window,
 
 def flash_decode_paged(
     q: jnp.ndarray,            # [B, 1, H, D]  one new token per slot
-    kp: jnp.ndarray,           # [P, page_size, Hkv, D]  K page pool
-    vp: jnp.ndarray,           # [P, page_size, Hkv, D]  V page pool
+    kp: jnp.ndarray,           # [P, Hkv, page_size, D]  K page pool
+    vp: jnp.ndarray,           # [P, Hkv, page_size, D]  V page pool
     table: jnp.ndarray,        # [B, pages_per_slot] int32; -1 unmapped
     *,
     pos=0,                     # scalar, or (B,) per-slot depth vector

@@ -116,7 +116,10 @@ def attention_ref(
     if window is not None:
         mask &= k_pos > q_pos - window
     logits = jnp.where(mask[:, None], logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
+    # A row with no valid key attends to nothing: its output is zero,
+    # as in every kernel, not the mean of V a softmax over -1e30 gives.
+    p = jnp.where(mask[:, None].any(axis=-1, keepdims=True),
+                  jax.nn.softmax(logits, axis=-1), 0.0)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, vf)
     return out.astype(q.dtype)
 
@@ -173,7 +176,7 @@ def attention_fwd_ref(
 
 
 def paged_gather_ref(
-    kp: jnp.ndarray,             # [P, page_size, Hkv, D] page pool
+    kp: jnp.ndarray,             # [P, Hkv, page_size, D] page pool
     table: jnp.ndarray,          # [B, pages_per_slot] int32; -1 unmapped
     scales: jnp.ndarray | None = None,   # [P, Hkv, page_size] f32
 ) -> jnp.ndarray:
@@ -184,19 +187,17 @@ def paged_gather_ref(
     int8 pools dequantize against the per-(position, head) scales.
     Returns [B, pages_per_slot*page_size, Hkv, D]."""
     b, pp = table.shape
-    n_pages, ps, hkv, d = kp.shape
+    n_pages, hkv, ps, d = kp.shape
     idx = jnp.maximum(jnp.asarray(table, jnp.int32), 0)
-    gathered = kp[idx]                          # (B, pp, ps, Hkv, D)
+    gathered = kp[idx]                          # (B, pp, Hkv, ps, D)
     if scales is not None:
-        s = scales[idx]                         # (B, pp, Hkv, ps)
-        gathered = gathered.astype(jnp.float32) \
-            * s.transpose(0, 1, 3, 2)[..., None]
-    return gathered.reshape(b, pp * ps, hkv, d)
+        gathered = gathered.astype(jnp.float32) * scales[idx][..., None]
+    return gathered.transpose(0, 1, 3, 2, 4).reshape(b, pp * ps, hkv, d)
 
 
 def flash_decode_paged_ref(
     q: jnp.ndarray,              # [B, 1, H, D]
-    kp: jnp.ndarray,             # [P, page_size, Hkv, D]
+    kp: jnp.ndarray,             # [P, Hkv, page_size, D]
     vp: jnp.ndarray,
     table: jnp.ndarray,          # [B, pages_per_slot] int32
     *,

@@ -45,12 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from repro.kernels import compiler_params, row_to_column
 
 
 def _segsum(a: jnp.ndarray) -> jnp.ndarray:
@@ -124,20 +119,20 @@ def ssd_chunked(
     return y, s_final
 
 
-def _ssd_chunk_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_ref):
+def _ssd_chunk_kernel(x_ref, cs_ref, b_ref, c_ref, y_ref, state_ref):
     q = x_ref.shape[2]
     x = x_ref[0, 0].astype(jnp.float32)       # (Q, BP)
-    a = a_ref[0, 0].astype(jnp.float32)       # (Q,)
+    cs = cs_ref[0, 0]                         # (1, Q) f32 cumsum(a) row
     b = b_ref[0, 0].astype(jnp.float32)       # (Q, N)
     c = c_ref[0, 0].astype(jnp.float32)       # (Q, N)
 
-    cs = jnp.cumsum(a)
+    cs_col = row_to_column(cs)                # the same values, (Q, 1)
     ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     # mask the log-space argument BEFORE exp: the upper triangle of
     # cs_i - cs_j is positive and overflows for strong decays, and a
     # post-exp where() would propagate NaN through the VJP.
-    ldec = jnp.exp(jnp.where(jj <= ii, cs[:, None] - cs[None, :], -jnp.inf))
+    ldec = jnp.exp(jnp.where(jj <= ii, cs_col - cs, -jnp.inf))
 
     scores = jax.lax.dot_general(                     # C Bᵀ: (Q, Q)
         c, b, (((1,), (1,)), ((), ())),
@@ -146,9 +141,9 @@ def _ssd_chunk_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_ref):
         scores * ldec, x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-    decay_end = jnp.exp(cs[-1] - cs)                  # (Q,)
+    decay_end = jnp.exp(cs_col[q - 1:] - cs_col)      # (Q, 1)
     state = jax.lax.dot_general(                      # Bᵀ diag(d) x: (N, BP)
-        b * decay_end[:, None], x, (((0,), (0,)), ((), ())),
+        b * decay_end, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     y_ref[0, 0] = y.astype(y_ref.dtype)
@@ -177,17 +172,17 @@ def ssd_intra_chunk(
     if p % bp:
         bp = p
     grid = (bh, nc, p // bp)
-    params = {}
-    if _HAS_PLTPU and not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
-        )
+    # The in-chunk cumulative decay is an XLA prefix sum: Mosaic has no
+    # cumsum, and the kernel needs nothing of `a` but its running sum.
+    cs = jnp.cumsum(a.astype(jnp.float32), axis=-1)
     return pl.pallas_call(
         _ssd_chunk_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, q, bp), lambda i, j, k: (i, j, 0, k)),
-            pl.BlockSpec((1, 1, q), lambda i, j, k: (i, j, 0)),
+            # cumsum(a) as (BH, nc, 1, Q): a lane-dense (1, Q) block
+            # satisfies the TPU (8, 128) rule on the last two dims
+            pl.BlockSpec((1, 1, 1, q), lambda i, j, k: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, q, n), lambda i, j, k: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, q, n), lambda i, j, k: (i, j, 0, 0)),
         ],
@@ -200,8 +195,9 @@ def ssd_intra_chunk(
             jax.ShapeDtypeStruct((bh, nc, n, p), jnp.float32),
         ],
         interpret=interpret,
-        **params,
-    )(x, a, b, c)
+        name="ssd_intra_chunk",
+        compiler_params=compiler_params("parallel", "parallel", "parallel"),
+    )(x, cs.reshape(bh, nc, 1, q), b, c)
 
 
 def ssd_pallas(

@@ -8,32 +8,30 @@ benches see the real 1-CPU world).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
 
-    def axis_kw(n: int) -> dict:
-        """kwargs for jax.make_mesh: n Auto axes (compat shim — older
-        jax has no AxisType and Auto is the only behaviour)."""
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:
-    def axis_kw(n: int) -> dict:
-        return {}
+def auto_axes(n: int) -> tuple:
+    """axis_types for jax.make_mesh: n Auto axes (the partitioner, not
+    the program, decides shardings inside jit)."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2x16x16 = 512 chips across 2 pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_kw(len(axes)))
+    return jax.make_mesh(shape, axes, auto_axes(len(axes)))
 
 
-def make_host_mesh(model_parallel: int = 1):
-    """Mesh over whatever devices actually exist (tests, examples)."""
-    n = len(jax.devices())
+def make_host_mesh(model_parallel: int = 1, devices: int | None = None):
+    """(data, model) mesh over the first `devices` devices that exist
+    (default: all of them)."""
+    devs = jax.devices()[:devices]
+    n = len(devs)
     assert n % model_parallel == 0
     return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"), **axis_kw(2))
+                         ("data", "model"), auto_axes(2), devices=devs)
 
 
 def dp_axes(multi_pod: bool):

@@ -47,6 +47,7 @@ from repro import tuning
 from repro.configs import ARCH_NAMES, get_config
 from repro.core import policy as policy_mod
 from repro.core.policy import LEGACY_BACKEND_NAMES, Policy
+from repro.launch import compile_cache
 from repro.models import model as M
 from repro.serving import DEFAULT_PREFILL_CHUNK, FaultInjector, \
     ServingEngine, TRACES, make_sampler, make_trace, prefix_heavy_trace, \
@@ -111,7 +112,11 @@ def check_outputs(cfg, engine, requests):
     """Hard output contract (replaces the vacuous isfinite-on-int check):
     every emitted token is a real vocab id, the engine's aggregate token
     count matches the per-request streams, every request reached a
-    terminal state, and FINISHED requests generated their full quota."""
+    terminal state, FINISHED requests generated their full quota, and
+    the engine never fell back to xla unless a chaos injector was armed
+    (a real kernel error propagates; only injected faults degrade)."""
+    assert not engine.degraded or engine.injector is not None, \
+        "engine degraded to the xla backend with no fault injector armed"
     for req in requests:
         toks = np.asarray(req.generated)
         if req.status == FINISHED:
@@ -244,6 +249,7 @@ def main(argv=None):
     if args.check_exact and args.sampler != "greedy":
         ap.error("--check-exact requires --sampler greedy")
 
+    compile_cache.enable()
     cfg = get_config(args.arch, reduced=args.reduced)
     policy = Policy.from_backend(args.backend)
     policy = policy.replace(kv_layout=args.kv_layout, quant_kv=args.quant_kv)

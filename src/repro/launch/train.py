@@ -16,7 +16,7 @@ import argparse
 import time
 
 import jax
-import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import tuning
 from repro.checkpoint.checkpointer import Checkpointer
@@ -27,6 +27,7 @@ from repro.data.pipeline import SyntheticLM
 from repro.distributed import sharding as shard_rules
 from repro.distributed.context import mesh_context
 from repro.distributed.fault_tolerance import (FailureInjector, Supervisor)
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.optim.adamw import AdamW, cosine_schedule
 from repro.training import train_loop as TL
@@ -46,7 +47,15 @@ def build(args):
             policy=policy if policy.autotune == "cached" else None,
             autotune=args.autotune, backward=True)
         print(tuning.describe_warm_start(rep))
-    mesh = mesh_lib.make_host_mesh(args.model_parallel)
+    mesh = mesh_lib.make_host_mesh(args.model_parallel,
+                                   devices=args.devices or None)
+    if (mesh.size > 1 and policy.backend != "xla"
+            and not policy.resolved_interpret):
+        # XLA cannot partition a compiled Mosaic kernel across a mesh;
+        # each kernel call would need a shard_map (a ROADMAP item)
+        raise SystemExit(
+            f"--backend {args.backend} runs on one device only; train on "
+            f"{mesh.size} devices with --backend xla")
     opt = AdamW(lr=cosine_schedule(args.lr, args.warmup, args.steps),
                 clip_norm=1.0)
     data = SyntheticLM(vocab=cfg.vocab, seq_len=args.seq, batch=args.batch,
@@ -54,11 +63,29 @@ def build(args):
     with mesh_context(mesh):
         state = TL.init_state(cfg, opt, jax.random.PRNGKey(args.seed),
                               compress=args.compress)
-    pspecs = shard_rules.param_specs(state.params, mesh)
+    state = place_state(state, mesh)
     step_fn = jax.jit(TL.make_train_step(cfg, opt, accum=args.accum,
                                          compress=args.compress),
                       donate_argnums=(0,))
     return cfg, mesh, state, step_fn, data
+
+
+def place_state(state, mesh):
+    """Shard the parameters (and every optimizer / error-feedback tree
+    shaped like them) over `mesh` by the rules in distributed.sharding;
+    scalars such as the step counter are replicated."""
+    psh = shard_rules.shardings_for(
+        mesh, shard_rules.param_specs(state.params, mesh))
+    like_params = lambda t: jax.device_put(t, psh)
+    replicated = NamedSharding(mesh, P())
+    return state._replace(
+        params=like_params(state.params),
+        opt=state.opt._replace(step=jax.device_put(state.opt.step,
+                                                   replicated),
+                               m=like_params(state.opt.m),
+                               v=like_params(state.opt.v)),
+        ef=None if state.ef is None
+        else state.ef._replace(error=like_params(state.ef.error)))
 
 
 def main(argv=None):
@@ -74,6 +101,8 @@ def main(argv=None):
     ap.add_argument("--warmup", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--model-parallel", type=int, default=1)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="train on the first N devices (0 = all)")
     ap.add_argument("--backend", choices=LEGACY_BACKEND_NAMES, default="xla",
                     help="GEMM backend for every dense contraction; "
                          "constructs the run's execution Policy "
@@ -89,6 +118,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     cfg, mesh, state, step_fn, data = build(args)
     n_params = sum(x.size for x in jax.tree.leaves(state.params))
     print(f"arch={cfg.name} params={n_params/1e6:.2f}M "
@@ -102,8 +132,10 @@ def main(argv=None):
             print(f"step {step:5d} loss {losses[-1]:.4f} "
                   f"gnorm {float(metrics['grad_norm']):.3f} {dt*1e3:.0f}ms")
 
+    batch_sharding = NamedSharding(mesh, P("data"))
+
     def run_step(state, step):
-        batch = jax.tree.map(jnp.asarray, data.batch_at(step))
+        batch = jax.device_put(data.batch_at(step), batch_sharding)
         with mesh_context(mesh):
             return step_fn(state, batch)
 
@@ -130,7 +162,7 @@ def main(argv=None):
             on_metrics(step, metrics, time.perf_counter() - t1)
         print(f"done {args.steps} steps in {time.time()-t0:.1f}s")
     print(f"final loss {losses[-1]:.4f} (first {losses[0]:.4f})")
-    return losses
+    return losses, state
 
 
 if __name__ == "__main__":

@@ -299,7 +299,7 @@ def attn_apply(
     enc_kv: Optional[tuple] = None,  # cross-attn: precomputed (k, v)
     kv_table: Optional[jnp.ndarray] = None,  # (B, pages_per_slot) page table:
                                    # cache is a PAGE POOL {"k","v"[,"ks","vs"]}
-                                   # of (P, page_size, Hkv, Dh) pages instead
+                                   # of (P, Hkv, page_size, Dh) pages instead
                                    # of per-slot rows (decode only)
     policy: Optional[Policy] = None,
     backend: Optional[str] = None,   # deprecated string shim
@@ -353,7 +353,7 @@ def attn_apply(
         # table entries route out of bounds; mode="drop" skips them.
         assert pos_vec, "paged KV cache requires per-slot positions"
         pos = jnp.asarray(cache_pos, jnp.int32)
-        n_pages, page_sz = cache["k"].shape[0], cache["k"].shape[1]
+        n_pages, page_sz = cache["k"].shape[0], cache["k"].shape[2]
         bidx = jnp.arange(pos.shape[0])
         wpos = pos[:, None] + jnp.arange(t, dtype=jnp.int32)[None]  # (B,T)
         drop = pos[:, None] < 0
@@ -371,16 +371,18 @@ def attn_apply(
             # page's scales sit lane-contiguous next to its rows.
             kq, ksc = _prec.quantize_kv(k)
             vq, vsc = _prec.quantize_kv(v)
-            new_cache["k"] = cache["k"].at[phys, off].set(kq, mode="drop")
-            new_cache["v"] = cache["v"].at[phys, off].set(vq, mode="drop")
+            new_cache["k"] = cache["k"].at[phys, :, off].set(
+                kq, mode="drop")
+            new_cache["v"] = cache["v"].at[phys, :, off].set(
+                vq, mode="drop")
             new_cache["ks"] = cache["ks"].at[phys, :, off].set(
                 ksc, mode="drop")
             new_cache["vs"] = cache["vs"].at[phys, :, off].set(
                 vsc, mode="drop")
         else:
-            new_cache["k"] = cache["k"].at[phys, off].set(
+            new_cache["k"] = cache["k"].at[phys, :, off].set(
                 k.astype(cache["k"].dtype), mode="drop")
-            new_cache["v"] = cache["v"].at[phys, off].set(
+            new_cache["v"] = cache["v"].at[phys, :, off].set(
                 v.astype(cache["v"].dtype), mode="drop")
         if t == 1:
             # Only pallas/xla have a paged gather; other backends
@@ -399,16 +401,18 @@ def attn_apply(
             # The gather materializes (B, Tmax) rows once per verify
             # round; a paged multi-query kernel is the TPU follow-up.
             tclamp = jnp.maximum(kv_table, 0)
-            kd = new_cache["k"][tclamp]       # (B, Ps, ps, Hkv, Dh)
+            kd = new_cache["k"][tclamp]       # (B, Ps, Hkv, ps, Dh)
             vd = new_cache["v"][tclamp]
             if "ks" in cache:
-                ks = new_cache["ks"][tclamp].transpose(0, 1, 3, 2)
-                vs = new_cache["vs"][tclamp].transpose(0, 1, 3, 2)
-                kd = kd.astype(jnp.float32) * ks[..., None]
-                vd = vd.astype(jnp.float32) * vs[..., None]
+                kd = kd.astype(jnp.float32) \
+                    * new_cache["ks"][tclamp][..., None]
+                vd = vd.astype(jnp.float32) \
+                    * new_cache["vs"][tclamp][..., None]
             b_, ps_ = tclamp.shape
-            kd = kd.reshape(b_, ps_ * page_sz, cfg.n_kv_heads, dh)
-            vd = vd.reshape(b_, ps_ * page_sz, cfg.n_kv_heads, dh)
+            kd = kd.transpose(0, 1, 3, 2, 4).reshape(
+                b_, ps_ * page_sz, cfg.n_kv_heads, dh)
+            vd = vd.transpose(0, 1, 3, 2, 4).reshape(
+                b_, ps_ * page_sz, cfg.n_kv_heads, dh)
             nv = jnp.asarray(t if n_valid is None else n_valid, jnp.int32)
             kv_len = jnp.where(pos < 0, 0, pos + nv)
             # pool width Ps*ps need not divide attn_chunk; page_sz does.
@@ -453,7 +457,15 @@ def attn_apply(
                                                  v.astype(cache["v"].dtype),
                                                  cache_pos, axis=1)
         new_cache = {"k": ck, "v": cv}
-        if cfg.window is not None and t == 1 and cache["k"].shape[1] > 2 * cfg.window:
+        if (pol.backend == "pallas" and isinstance(cache_pos, int)
+                and cache_pos == 0 and _flash_shapes_ok(t, t)):
+            # Prefill into an empty cache: the valid keys are exactly
+            # this chunk's k/v, so the fused flash kernel runs on them
+            # instead of the chunked path masking the whole cache depth.
+            out = attend(q, k, v, causal=True, window=cfg.window,
+                         chunk=cfg.attn_chunk, io_dtype=io_dtype,
+                         policy=pol)
+        elif cfg.window is not None and t == 1 and cache["k"].shape[1] > 2 * cfg.window:
             # SWA decode fast-path: only the last `window` cache entries
             # can attend — slice them out instead of scanning 500k keys.
             start = jnp.maximum(cache_pos + 1 - cfg.window, 0)

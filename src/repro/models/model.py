@@ -211,7 +211,7 @@ def init_paged_cache(cfg, n_pages: int, page_size: int, max_slots: int,
     """Page-pool KV cache for continuous-batching decode (see
     serving.kv_pool for the host-side bookkeeping). Layout:
 
-        {"pages": {"k", "v": (L, n_pages, page_size, Hkv, Dh)
+        {"pages": {"k", "v": (L, n_pages, Hkv, page_size, Dh)
                    [, "ks", "vs": (L, n_pages, Hkv, page_size) f32]},
          "table": (max_slots, pages_per_slot) int32, -1 = unmapped}
 
@@ -224,14 +224,12 @@ def init_paged_cache(cfg, n_pages: int, page_size: int, max_slots: int,
         raise ValueError(
             f"paged KV cache supports dense/moe/vlm, not {cfg.family!r}")
     dh = cfg.resolved_head_dim
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, dh)
+    shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, dh)
     if quant_kv == "int8":
         pages = {"k": jnp.zeros(shape, jnp.int8),
                  "v": jnp.zeros(shape, jnp.int8),
-                 "ks": jnp.zeros(shape[:2] + (cfg.n_kv_heads, page_size),
-                                 jnp.float32),
-                 "vs": jnp.zeros(shape[:2] + (cfg.n_kv_heads, page_size),
-                                 jnp.float32)}
+                 "ks": jnp.zeros(shape[:-1], jnp.float32),
+                 "vs": jnp.zeros(shape[:-1], jnp.float32)}
     elif quant_kv == "off":
         dtype = jnp.dtype(cfg.dtype)
         pages = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}

@@ -60,12 +60,12 @@ Fault tolerance (docs/ARCHITECTURE.md §Fault tolerance):
   * Numeric guards — after every decode step a sentinel scans each
     active row's logits; a non-finite row quarantines ONLY that slot
     (terminal QUARANTINED status + diagnostic) while the rest of the
-    batch keeps decoding. Repeated kernel-level faults (RuntimeError
-    out of the jitted step) degrade the engine's policy to the `xla`
-    registry backend with a once-per-process warning instead of
-    crashing. (Step retry after a fault assumes the donated cache
-    buffer survives — true on CPU/interpret where donation is a no-op;
-    a real-device deployment would pair this with cache snapshots.)
+    batch keeps decoding. Injected kernel faults (SimulatedKernelFault
+    from an armed chaos injector) are retried, and once they repeat
+    the engine's policy degrades to the `xla` registry backend with a
+    once-per-process warning. A real kernel error is never absorbed:
+    it propagates out of the step, so a backend that cannot compile or
+    run on the device fails the run instead of hiding behind xla.
   * Chaos harness — a `serving.faults.FaultInjector` drives all of the
     above at scripted step counts for deterministic tests and the
     `--chaos-*` serve CLI flags.
@@ -86,7 +86,7 @@ from repro.core import policy as _pol
 from repro.core import precision as _prec
 from repro.distributed.fault_tolerance import StragglerDetector
 from repro.models import model as M
-from repro.serving.faults import FaultInjector
+from repro.serving.faults import FaultInjector, SimulatedKernelFault
 from repro.serving.kv_pool import KVPagePool, KVPoolExhausted
 from repro.serving.request import (ACTIVE, CANCELLED, FINISHED, QUARANTINED,
                                    TERMINAL, WAITING, Request, percentile)
@@ -323,14 +323,14 @@ class ServingEngine:
         phys = jnp.int32(phys)
         for name in ("k", "v"):
             rows = jax.lax.dynamic_slice_in_dim(
-                sub[name][:, 0], start, ps, axis=1)      # (L, ps, Hkv, Dh)
+                sub[name][:, 0], start, ps, axis=1
+            ).transpose(0, 2, 1, 3)                      # (L, Hkv, ps, Dh)
             if "ks" in pages:
-                q, s = _prec.quantize_kv(rows)           # s: (L, ps, Hkv)
+                q, s = _prec.quantize_kv(rows)           # s: (L, Hkv, ps)
                 pages[name] = jax.lax.dynamic_update_slice(
                     pages[name], q[:, None], (z, phys, z, z, z))
                 pages[name + "s"] = jax.lax.dynamic_update_slice(
-                    pages[name + "s"], s.transpose(0, 2, 1)[:, None],
-                    (z, phys, z, z))
+                    pages[name + "s"], s[:, None], (z, phys, z, z))
             else:
                 pages[name] = jax.lax.dynamic_update_slice(
                     pages[name], rows[:, None].astype(pages[name].dtype),
@@ -551,11 +551,11 @@ class ServingEngine:
                 RuntimeWarning, stacklevel=2)
 
     def _run_step(self, step_idx: int):
-        """One guarded jitted decode step: kernel-level faults are
+        """One guarded jitted decode step: injected kernel faults are
         retried, and once they repeat past `kernel_fault_threshold` the
         engine rebuilds its steps on the xla backend instead of
         crashing. A step that exhausts its retries counts as crashed and
-        re-raises."""
+        re-raises; any other error propagates at once."""
         tokens = jnp.asarray(self._tokens)
         pos = jnp.asarray(self._pos)
         attempts = 0
@@ -564,7 +564,7 @@ class ServingEngine:
                 if self.injector is not None:
                     self.injector.before_kernel(step_idx)
                 return self._step(self.params, tokens, pos, self.cache)
-            except RuntimeError as e:   # kernel faults, incl. simulated
+            except SimulatedKernelFault as e:
                 attempts += 1
                 self.kernel_faults += 1
                 if attempts > self.max_step_retries:

@@ -386,16 +386,14 @@ def tune_flash_decode_paged(
     n_pages = batch * pp
     depth = pp * page_size
     q = jnp.asarray(rng.normal(size=(batch, 1, heads, d)), dtype)
-    kp = jnp.asarray(rng.normal(size=(n_pages, page_size, heads, d)), dtype)
-    vp = jnp.asarray(rng.normal(size=(n_pages, page_size, heads, d)), dtype)
+    kp = jnp.asarray(rng.normal(size=(n_pages, heads, page_size, d)), dtype)
+    vp = jnp.asarray(rng.normal(size=(n_pages, heads, page_size, d)), dtype)
     table = jnp.arange(n_pages, dtype=jnp.int32).reshape(batch, pp)
     pos_v = jnp.full((batch,), depth - 1 if pos is None else pos, jnp.int32)
     ks = vs = None
     if pol.quant_kv == "int8":
-        kp, ks = _prec.quantize_kv(kp)
+        kp, ks = _prec.quantize_kv(kp)      # ks: (P, Hkv, page_size)
         vp, vs = _prec.quantize_kv(vp)
-        ks = ks.transpose(0, 2, 1)          # (P, Hkv, page_size)
-        vs = vs.transpose(0, 2, 1)
     itemsize = 1 if pol.quant_kv == "int8" else jnp.dtype(dtype).itemsize
 
     return _sweep(

@@ -16,6 +16,7 @@ The reference oracle is _reference_generate from test_serving: one
 whole-prompt prefill + scalar-pos greedy decode, batch 1.
 """
 
+import dataclasses
 import warnings
 
 import jax
@@ -32,7 +33,13 @@ from test_serving import _reference_generate
 
 
 def _setup(arch="qwen3-0.6b", seed=0):
-    cfg = get_config(arch, reduced=True)
+    # f32 activations: XLA:CPU accumulates a one-row matmul in another
+    # order than a multi-row one (~1e-6 relative), so a bf16 model's
+    # greedy picks can differ between the engine's batched decode and
+    # the batch-1 reference on a near-tie. These tests pin the engine's
+    # slot bookkeeping, which f32 shows token-exactly.
+    cfg = dataclasses.replace(get_config(arch, reduced=True),
+                              dtype="float32")
     params = M.init_params(cfg, jax.random.PRNGKey(seed))
     return cfg, params
 
@@ -245,6 +252,36 @@ def test_kernel_fault_retry_exhaustion_counts_crashed_step():
     with pytest.raises(SimulatedKernelFault):
         eng.run()
     assert eng.crashed_steps == 1 and eng.kernel_faults == 1
+
+
+def test_real_kernel_error_propagates_without_degrade():
+    """Only injected faults degrade: a real error out of the jitted
+    decode step fails the run at once, on the backend it was given."""
+    cfg, params = _setup()
+    eng = ServingEngine(cfg, params, max_slots=1, max_len=32,
+                        policy=Policy(backend="pallas", interpret=True))
+    eng.submit(_prompts(cfg, [8], seed=49)[0], 4)   # prefill, then decode
+
+    def broken_step(*args):
+        raise RuntimeError("Mosaic failed to compile the kernel")
+
+    eng._step = broken_step
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        eng.run()
+    assert not eng.degraded and eng.policy.backend == "pallas"
+    assert eng.kernel_faults == 0 and eng.decode_steps == 0
+
+
+def test_check_outputs_fails_a_degraded_run_without_injector():
+    from repro.launch.serve import check_outputs
+    cfg, params = _setup()
+    eng = ServingEngine(cfg, params, max_slots=1, max_len=32)
+    req = eng.submit(_prompts(cfg, [10], seed=51)[0], 3)
+    eng.run()
+    check_outputs(cfg, eng, [req])
+    eng.degraded = True
+    with pytest.raises(AssertionError, match="no fault injector"):
+        check_outputs(cfg, eng, [req])
 
 
 # ------------------------------------------------- deadlines + cancellation

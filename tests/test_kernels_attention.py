@@ -3,6 +3,7 @@ backward, the q_len=1 decode kernel, and the attention() router — every
 Pallas path in interpret mode against the dense oracle and the chunked
 XLA composition it replaced."""
 
+import pathlib
 import subprocess
 import sys
 import textwrap
@@ -18,6 +19,8 @@ from repro.kernels.ops import flash_attention
 from repro.kernels.ref import (attention_bwd_ref, attention_fwd_ref,
                                attention_ref, _LSE_EMPTY)
 from repro.models.attention import attention, chunked_attention
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 _PI = Policy(backend="pallas", interpret=True)
 _XLA = Policy(backend="xla")
@@ -279,5 +282,5 @@ def test_float64_reroutes_to_xla():
         print("OK")
     """)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, cwd="/root/repo", timeout=300)
+                         text=True, cwd=REPO_ROOT, timeout=300)
     assert out.returncode == 0 and "OK" in out.stdout, out.stderr[-2000:]

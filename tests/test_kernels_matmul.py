@@ -2,6 +2,8 @@
 over shapes x dtypes — including the paper's float / double / complex
 matrix (Table 2)."""
 
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,8 @@ from repro.kernels import ops
 from repro.kernels.matmul import matmul_tiled
 from repro.kernels.matmul_naive import matmul_naive
 from repro.kernels.ref import matmul_ref
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 SHAPES = [
     (8, 8, 8),
@@ -73,7 +77,7 @@ def test_float64_interpret():
         print("OK")
     """)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, cwd="/root/repo", timeout=300)
+                         text=True, cwd=REPO_ROOT, timeout=300)
     assert out.returncode == 0 and "OK" in out.stdout, out.stderr[-2000:]
 
 

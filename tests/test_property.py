@@ -236,8 +236,8 @@ def test_flash_decode_paged_backends_match_reference(ps, pp, d, group,
     h = hkv * group
     n_pages = B * pp + 1                     # one page never mapped
     q = jnp.asarray(rng.normal(size=(B, 1, h, d)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, d)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(n_pages, hkv, ps, d)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(n_pages, hkv, ps, d)), jnp.float32)
     table = jnp.asarray(rng.permutation(n_pages)[:B * pp].reshape(B, pp),
                         jnp.int32)
     table = table.at[1, -1].set(-1)          # slot 1: last page unmapped
@@ -245,10 +245,8 @@ def test_flash_decode_paged_backends_match_reference(ps, pp, d, group,
                        int(rng.integers(0, ps * (pp - 1)))], jnp.int32)
     ks = vs = None
     if quant:
-        kp, ks = precision.quantize_kv(kp)
+        kp, ks = precision.quantize_kv(kp)   # ks: (P, hkv, ps)
         vp, vs = precision.quantize_kv(vp)
-        ks = ks.transpose(0, 2, 1)           # (P, ps, hkv) -> (P, hkv, ps)
-        vs = vs.transpose(0, 2, 1)
     ref = kref.flash_decode_paged_ref(q, kp, vp, table, pos=pos,
                                       window=window, ks=ks, vs=vs)
     ref = ref.astype(jnp.float32)

@@ -4,7 +4,9 @@ collective parsing, report construction."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from repro.core import hw
 from repro.roofline import hlo as H
 from repro.roofline.analysis import build_report, count_params, model_flops
 import repro.configs as C
@@ -160,3 +162,12 @@ def test_kv_capacity_model_prefix_heavy_2x():
     assert q8["capacity_ratio"] >= 2.0
     assert q8["paged_slots"] > f32["paged_slots"]   # int8 pages stack up
     assert q8["n_pages"] > f32["n_pages"]
+
+
+def test_chip_for_looks_up_measured_devices_by_device_kind():
+    """Peaks for a measured device come from its device_kind; a kind
+    with no table entry raises instead of scoring against another
+    chip's peaks."""
+    assert hw.chip_for("TPU v5 lite") is hw.TPU_V5E
+    with pytest.raises(ValueError, match="no peak table entry"):
+        hw.chip_for("cpu")
