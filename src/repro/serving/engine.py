@@ -20,8 +20,17 @@ cache, then copies that cache into the slot's row of the pooled cache.
 Prompt lengths are bucketed down to a multiple of `prefill_chunk` for
 the jitted prefill (bounding compile count under mixed-length traffic);
 the 0..chunk-1 remainder tokens run through the same serve_step at
-batch 1, so the admitted state is exactly what a full-length prefill
+batch 1 (jitted as `admit_token_step`, so a profile tells the two
+apart), so the admitted state is exactly what a full-length prefill
 would have produced — tests/test_serving.py asserts token-exactness.
+
+Spans (repro.core.spans, recorded only when turned on): every step()
+is a `repro.engine.step`, split into `.schedule`, `.admit` (`.prefill`,
+`.token_steps`, `.slot_copy`, `.first_token`) and `.decode`
+(`.dispatch`, `.logits_read`, `.sample`); `repro.engine.queue` is a
+request's wait from submission (or its later arrival_time) to its first
+admission. prefill_time and the decode step times are read from the
+same stamps as the admit and decode spans.
 
 Family notes: attention caches copy per-slot KV rows; ssm/hybrid copy
 recurrent state rows (their "position" is implicit in the state, the
@@ -84,6 +93,7 @@ import numpy as np
 
 from repro.core import policy as _pol
 from repro.core import precision as _prec
+from repro.core import spans
 from repro.distributed.fault_tolerance import StragglerDetector
 from repro.models import model as M
 from repro.serving.faults import FaultInjector, SimulatedKernelFault
@@ -262,12 +272,12 @@ class ServingEngine:
 
         self.requests: List[Request] = []
         self._next_rid = 0
-        self._t0: Optional[float] = None
-        # aggregate counters
+        self._t0_ns: Optional[int] = None
+        # aggregate counters; prefill_time and _step_times come from the
+        # stamps of the repro.engine.admit and .decode spans
         self.prefill_tokens = 0
         self.prefill_time = 0.0
         self.decode_steps = 0
-        self.decode_time = 0.0
         self.decode_slot_steps = 0     # sum of active slots over steps
         self.tokens_emitted = 0
         self.peak_occupancy = 0
@@ -288,9 +298,14 @@ class ServingEngine:
         self._prefill = jax.jit(TL.make_prefill(self.cfg,
                                                 policy=self.policy),
                                 donate_argnums=(2,))
-        self._step = jax.jit(TL.make_serve_step(self.cfg,
-                                                policy=self.policy),
-                             donate_argnums=(3,))
+        serve_step = TL.make_serve_step(self.cfg, policy=self.policy)
+        self._step = jax.jit(serve_step, donate_argnums=(3,))
+
+        # the same step at batch 1 for admission's remainder tokens, under
+        # a name of its own in the profiler's trace (jit_admit_token_step)
+        def admit_token_step(params, token, pos, cache):
+            return serve_step(params, token, pos, cache)
+        self._admit_step = jax.jit(admit_token_step, donate_argnums=(3,))
         if self.spec is not None:
             self._vstep = jax.jit(TL.make_verify_step(self.cfg,
                                                       policy=self.policy),
@@ -384,15 +399,21 @@ class ServingEngine:
                       arrival_time=arrival_time, deadline=deadline,
                       priority=priority, enc_frames=enc_frames)
         self._next_rid += 1
+        req.t_submitted = self._now()
         self.requests.append(req)
         self.scheduler.submit(req)
         return req
 
     # -- clock ---------------------------------------------------------
     def _now(self) -> float:
-        if self._t0 is None:
-            self._t0 = time.perf_counter()
-        return time.perf_counter() - self._t0
+        return self._clock(time.perf_counter_ns())
+
+    def _clock(self, ns: int) -> float:
+        """Engine-clock seconds of a time.perf_counter_ns() stamp; the
+        clock starts at the engine's first use of it."""
+        if self._t0_ns is None:
+            self._t0_ns = ns
+        return (ns - self._t0_ns) / 1e9
 
     # -- admission (prefill path) ---------------------------------------
     def _copy_prefill(self, slot: int, sub, plan=None) -> None:
@@ -419,37 +440,47 @@ class ServingEngine:
         plan = None
         if self.pool is not None:
             plan = self.pool.admit_slot(slot, ctx, req.remaining_tokens)
-        if req.t_admitted is None:
-            req.t_admitted = self._now()
         self._admissions += 1
-        t0 = time.perf_counter()
 
         L = len(ctx)
         chunk = self.prefill_chunk
         lb = L - (L % chunk) or L      # bucket down; short prompts exact
-        batch: Dict[str, Any] = {"tokens": jnp.asarray(ctx[None, :lb])}
-        if self.cfg.family == "encdec":
-            batch["enc_frames"] = jnp.asarray(req.enc_frames[None])
-        sub = M.init_cache(self.cfg, 1, self.max_len)
-        logits, sub = self._prefill(self.params, batch, sub)
-        for i in range(lb, L):         # remainder: one-token steps
-            logits, sub = self._step(
-                self.params, jnp.asarray(ctx[None, None, i]),
-                jnp.int32(i), sub)
-        self._copy_prefill(slot, sub, plan)
-
-        row = np.asarray(logits)[0, -1, :self.cfg.vocab]
-        self.prefill_time += time.perf_counter() - t0
+        with spans.timed("repro.engine.admit", rid=req.rid, prompt_len=L,
+                         bucket=lb, token_steps=L - lb) as adm:
+            with spans.span("repro.engine.admit.prefill"):
+                batch: Dict[str, Any] = {
+                    "tokens": jnp.asarray(ctx[None, :lb])}
+                if self.cfg.family == "encdec":
+                    batch["enc_frames"] = jnp.asarray(req.enc_frames[None])
+                sub = M.init_cache(self.cfg, 1, self.max_len)
+                logits, sub = self._prefill(self.params, batch, sub)
+            with spans.span("repro.engine.admit.token_steps"):
+                for i in range(lb, L):         # remainder: one-token steps
+                    logits, sub = self._admit_step(
+                        self.params, jnp.asarray(ctx[None, None, i]),
+                        jnp.int32(i), sub)
+            with spans.span("repro.engine.admit.slot_copy"):
+                self._copy_prefill(slot, sub, plan)
+            with spans.span("repro.engine.admit.first_token"):
+                row = np.asarray(logits)[0, -1, :self.cfg.vocab]
+                # same sentinel as decode: a poisoned prefill quarantines
+                # this request only, never the engine
+                tok = self.sampler(row) if np.isfinite(row).all() else None
+        self.prefill_time += adm.seconds
         self.prefill_tokens += L
-        now = self._now()
-        if not np.isfinite(row).all():
-            # same sentinel as decode: a poisoned prefill quarantines
-            # this request only, never the engine
+        if req.t_admitted is None:
+            req.t_admitted = self._clock(adm.start_ns)
+            # the wait before a first admission; a resume's wait after
+            # its preemption is not a queue wait of the request's own
+            spans.record("repro.engine.queue",
+                         self._t0_ns + round(req.t_due * 1e9), adm.start_ns,
+                         rid=req.rid)
+        now = self._clock(adm.end_ns)
+        if tok is None:
             req.error = "non-finite logits at admission prefill"
             self.quarantined += 1
             self._release(req, slot, QUARANTINED, now)
             return
-        tok = self.sampler(row)
         if req.t_first_token is None:
             req.t_first_token = now
         req.generated.append(tok)
@@ -609,51 +640,61 @@ class ServingEngine:
         if not active:
             raise ValueError("decode step with no active slots")
         step_idx = self.decode_steps
-        if self.pool is not None:
-            # Make every slot's write position privately owned BEFORE
-            # the jitted step scatters into it: a write into a shared
-            # page becomes a device page copy (CoW), a write past the
-            # mapped prefix allocates from the reservation made at
-            # admission (so this can never fail mid-stream).
-            for slot in active:
-                w = self.pool.prepare_write(slot, int(self._pos[slot]))
-                if w is not None and w.kind == "cow":
-                    self.cache = self._copy_pg(
-                        self.cache, jnp.int32(w.src), jnp.int32(w.dst))
-            self._sync_table()
-        if self.injector is not None:
-            for slot in self.injector.corrupt_slots(step_idx, tuple(active)):
-                self._poison_slot_cache(slot)
-        t0 = time.perf_counter()
-        logits, self.cache = self._run_step(step_idx)
-        rows = np.asarray(logits)[:, -1, :self.cfg.vocab]   # sync point
-        dt = time.perf_counter() - t0
-        self.decode_time += dt
+        with spans.timed("repro.engine.decode.dispatch") as disp:
+            if self.pool is not None:
+                # Make every slot's write position privately owned
+                # BEFORE the jitted step scatters into it: a write into a
+                # shared page becomes a device page copy (CoW), a write
+                # past the mapped prefix allocates from the reservation
+                # made at admission (so this can never fail mid-stream).
+                for slot in active:
+                    w = self.pool.prepare_write(slot, int(self._pos[slot]))
+                    if w is not None and w.kind == "cow":
+                        self.cache = self._copy_pg(
+                            self.cache, jnp.int32(w.src), jnp.int32(w.dst))
+                self._sync_table()
+            if self.injector is not None:
+                for slot in self.injector.corrupt_slots(step_idx,
+                                                        tuple(active)):
+                    self._poison_slot_cache(slot)
+            logits, self.cache = self._run_step(step_idx)
+        with spans.timed("repro.engine.decode.logits_read") as read:
+            rows = np.asarray(logits)[:, -1, :self.cfg.vocab]  # sync point
+        with spans.span("repro.engine.decode.sample"):
+            self._count_step(step_idx, disp.start_ns, read.end_ns,
+                             len(active))
+            if self.injector is not None:
+                rows = self.injector.poison_rows(step_idx, rows,
+                                                 tuple(active))
+            now = self._clock(read.end_ns)
+            for slot in sorted(active):
+                req = active[slot]
+                if not np.isfinite(rows[slot]).all():
+                    # quarantine ONLY the poisoned slot; co-scheduled rows
+                    # are untouched (their logits never mix across slots)
+                    req.error = f"non-finite logits at decode step {step_idx}"
+                    self.quarantined += 1
+                    self._release(req, slot, QUARANTINED, now)
+                    continue
+                tok = self.sampler(rows[slot])
+                req.generated.append(tok)
+                self.tokens_emitted += 1
+                if self._done(req, tok):
+                    self._finish(req, slot, now)
+                else:
+                    self._pos[slot] += 1
+                    self._tokens[slot, 0] = tok
+
+    def _count_step(self, step_idx: int, start_ns: int, end_ns: int,
+                    n_active: int) -> None:
+        """A decode step's counters: its time from dispatch to the host
+        holding every slot's logits, and its occupancy."""
+        dt = (end_ns - start_ns) / 1e9
         self._step_times.append(dt)
         self.straggler.observe(step_idx, dt)
         self.decode_steps += 1
-        self.decode_slot_steps += len(active)
-        self.peak_occupancy = max(self.peak_occupancy, len(active))
-        if self.injector is not None:
-            rows = self.injector.poison_rows(step_idx, rows, tuple(active))
-        now = self._now()
-        for slot in sorted(active):
-            req = active[slot]
-            if not np.isfinite(rows[slot]).all():
-                # quarantine ONLY the poisoned slot; co-scheduled rows
-                # are untouched (their logits never mix across slots)
-                req.error = f"non-finite logits at decode step {step_idx}"
-                self.quarantined += 1
-                self._release(req, slot, QUARANTINED, now)
-                continue
-            tok = self.sampler(rows[slot])
-            req.generated.append(tok)
-            self.tokens_emitted += 1
-            if self._done(req, tok):
-                self._finish(req, slot, now)
-            else:
-                self._pos[slot] += 1
-                self._tokens[slot, 0] = tok
+        self.decode_slot_steps += n_active
+        self.peak_occupancy = max(self.peak_occupancy, n_active)
 
     # -- speculative decode (draft round + ONE batched verification) ----
     def _spec_decode_once(self) -> None:
@@ -677,70 +718,69 @@ class ServingEngine:
             # a slot about to hit its budget proposes fewer drafts —
             # tokens past max_new would be drafted only to be dropped
             k_vec[slot] = min(k, req.remaining_tokens - 1)
-        t0 = time.perf_counter()
-        drafts, qprobs = self.spec.draft_round(self._tokens, self._pos,
-                                               k_vec)
-        vtokens = np.zeros((self.max_slots, k + 1), np.int32)
-        vtokens[:, 0] = self._tokens[:, 0]
-        vtokens[:, 1:] = drafts
-        n_tok = np.where(self._pos >= 0, k_vec + 1, 0).astype(np.int32)
-        if self.pool is not None:
-            # every position the verify scatter may write must be
-            # privately owned first; the admission reservation covers
-            # the full range (max write pos + k_vec stays short of the
-            # reserved last page), so this never fails mid-stream.
-            for slot in active:
-                p0 = int(self._pos[slot])
+        with spans.timed("repro.engine.decode.dispatch") as disp:
+            drafts, qprobs = self.spec.draft_round(self._tokens, self._pos,
+                                                   k_vec)
+            vtokens = np.zeros((self.max_slots, k + 1), np.int32)
+            vtokens[:, 0] = self._tokens[:, 0]
+            vtokens[:, 1:] = drafts
+            n_tok = np.where(self._pos >= 0, k_vec + 1, 0).astype(np.int32)
+            if self.pool is not None:
+                # every position the verify scatter may write must be
+                # privately owned first; the admission reservation covers
+                # the full range (max write pos + k_vec stays short of the
+                # reserved last page), so this never fails mid-stream.
                 ps = self.page_size
-                for j in range(p0 // ps, (p0 + int(n_tok[slot]) - 1) // ps + 1):
-                    w = self.pool.prepare_write(slot, j * ps)
-                    if w is not None and w.kind == "cow":
-                        self.cache = self._copy_pg(
-                            self.cache, jnp.int32(w.src), jnp.int32(w.dst))
-            self._sync_table()
-        logits, self.cache = self._vstep(
-            self.params, jnp.asarray(vtokens), jnp.asarray(self._pos),
-            jnp.asarray(n_tok), self.cache)
-        rows = np.asarray(logits)[:, :, :self.cfg.vocab]    # sync point
-        dt = time.perf_counter() - t0
-        self.decode_time += dt
-        self._step_times.append(dt)
-        self.straggler.observe(step_idx, dt)
-        self.decode_steps += 1
-        self.spec_rounds += 1
-        self.decode_slot_steps += len(active)
-        self.peak_occupancy = max(self.peak_occupancy, len(active))
-        now = self._now()
-        for slot in sorted(active):
-            req = active[slot]
-            nt = int(n_tok[slot])
-            if not np.isfinite(rows[slot, :nt]).all():
-                req.error = f"non-finite logits at decode step {step_idx}"
-                self.quarantined += 1
-                self._release(req, slot, QUARANTINED, now)
-                continue
-            kk = nt - 1
-            emitted, n_acc = self.sampler.speculative_accept(
-                rows[slot, :nt], drafts[slot, :kk],
-                None if qprobs is None else qprobs[slot, :kk])
-            req.draft_proposed += kk
-            req.draft_accepted += n_acc
-            self.spec_proposed += kk
-            self.spec_accepted += n_acc
-            n_cons = 0
-            finished = False
-            for tok in emitted:
-                req.generated.append(tok)
-                self.tokens_emitted += 1
-                n_cons += 1
-                if self._done(req, tok):   # eos truncates mid-round
-                    finished = True
-                    break
-            if finished:
-                self._finish(req, slot, now)
-            else:
-                self._pos[slot] += n_cons
-                self._tokens[slot, 0] = emitted[n_cons - 1]
+                for slot in active:
+                    p0 = int(self._pos[slot])
+                    for j in range(p0 // ps,
+                                   (p0 + int(n_tok[slot]) - 1) // ps + 1):
+                        w = self.pool.prepare_write(slot, j * ps)
+                        if w is not None and w.kind == "cow":
+                            self.cache = self._copy_pg(
+                                self.cache, jnp.int32(w.src),
+                                jnp.int32(w.dst))
+                self._sync_table()
+            logits, self.cache = self._vstep(
+                self.params, jnp.asarray(vtokens), jnp.asarray(self._pos),
+                jnp.asarray(n_tok), self.cache)
+        with spans.timed("repro.engine.decode.logits_read") as read:
+            rows = np.asarray(logits)[:, :, :self.cfg.vocab]  # sync point
+        with spans.span("repro.engine.decode.sample"):
+            self._count_step(step_idx, disp.start_ns, read.end_ns,
+                             len(active))
+            self.spec_rounds += 1
+            now = self._clock(read.end_ns)
+            for slot in sorted(active):
+                req = active[slot]
+                nt = int(n_tok[slot])
+                if not np.isfinite(rows[slot, :nt]).all():
+                    req.error = f"non-finite logits at decode step {step_idx}"
+                    self.quarantined += 1
+                    self._release(req, slot, QUARANTINED, now)
+                    continue
+                kk = nt - 1
+                emitted, n_acc = self.sampler.speculative_accept(
+                    rows[slot, :nt], drafts[slot, :kk],
+                    None if qprobs is None else qprobs[slot, :kk])
+                req.draft_proposed += kk
+                req.draft_accepted += n_acc
+                self.spec_proposed += kk
+                self.spec_accepted += n_acc
+                n_cons = 0
+                finished = False
+                for tok in emitted:
+                    req.generated.append(tok)
+                    self.tokens_emitted += 1
+                    n_cons += 1
+                    if self._done(req, tok):   # eos truncates mid-round
+                        finished = True
+                        break
+                if finished:
+                    self._finish(req, slot, now)
+                else:
+                    self._pos[slot] += n_cons
+                    self._tokens[slot, 0] = emitted[n_cons - 1]
 
     # -- driving -------------------------------------------------------
     def step(self) -> bool:
@@ -748,31 +788,41 @@ class ServingEngine:
         for a pool-starved FCFS head when a victim exists), then run one
         decode step if any slot is active. Returns False when all work
         is drained."""
-        while True:
-            now = self._now()
-            for req in self.scheduler.drop_expired(now):
-                req.t_finished = now
-                self.expired += 1
-            req = self.scheduler.next_admission(now)
-            if req is None:
-                break
-            if self.pool is not None:
-                denied = (self.injector is not None
-                          and self.injector.deny_admission(self._admissions))
-                ok = not denied and self.pool.can_admit(
-                    req.context_tokens(), req.remaining_tokens)
-                while not ok and self._preempt_for(req):
-                    ok = self.pool.can_admit(req.context_tokens(),
-                                             req.remaining_tokens)
-                if not ok:
-                    break   # head waits for pages to free
-            self._admit(req)
-        if self.scheduler.n_active:
-            if self.spec is not None:
-                self._spec_decode_once()
-            else:
-                self._decode_once()
-        return self.scheduler.has_work()
+        with spans.span("repro.engine.step"):
+            while True:
+                with spans.span("repro.engine.schedule"):
+                    req = self._next_admission()
+                if req is None:
+                    break
+                self._admit(req)
+            if self.scheduler.n_active:
+                with spans.span("repro.engine.decode", step=self.decode_steps,
+                                active=self.scheduler.n_active):
+                    if self.spec is not None:
+                        self._spec_decode_once()
+                    else:
+                        self._decode_once()
+            return self.scheduler.has_work()
+
+    def _next_admission(self) -> Optional[Request]:
+        """Drop expired waiters and return the request to admit now, or
+        None; a pool-starved FCFS head preempts a victim where one
+        exists, and otherwise waits for pages to free."""
+        now = self._now()
+        for req in self.scheduler.drop_expired(now):
+            req.t_finished = now
+            self.expired += 1
+        req = self.scheduler.next_admission(now)
+        if req is None or self.pool is None:
+            return req
+        denied = (self.injector is not None
+                  and self.injector.deny_admission(self._admissions))
+        ok = not denied and self.pool.can_admit(req.context_tokens(),
+                                                req.remaining_tokens)
+        while not ok and self._preempt_for(req):
+            ok = self.pool.can_admit(req.context_tokens(),
+                                     req.remaining_tokens)
+        return req if ok else None
 
     def run(self, *, idle_sleep: float = 1e-3) -> Dict[str, Any]:
         """Drive to completion; returns the stats report."""
@@ -793,7 +843,7 @@ class ServingEngine:
         n_emitted = sum(r.n_generated for r in self.requests)
         assert n_emitted == self.tokens_emitted, \
             (n_emitted, self.tokens_emitted)
-        waits = [r.t_admitted - r.arrival_time for r in self.requests
+        waits = [r.t_admitted - r.t_due for r in self.requests
                  if r.t_admitted is not None]
         # goodput: only tokens of requests that FINISHED (and met their
         # deadline, if they had one) were worth emitting; everything a
@@ -817,7 +867,7 @@ class ServingEngine:
             "decode_tokens": decode_tokens,
             "decode_steps": self.decode_steps,
             "decode_tok_s": (self.decode_slot_steps
-                             / max(self.decode_time, 1e-9)),
+                             / max(sum(self._step_times), 1e-9)),
             "mean_occupancy": (self.decode_slot_steps
                                / max(self.decode_steps, 1)),
             "latency_p50_s": percentile(lat, 50),

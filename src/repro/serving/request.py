@@ -56,6 +56,7 @@ class Request:
     status: str = WAITING
     slot: int = -1
     generated: List[int] = dataclasses.field(default_factory=list)
+    t_submitted: Optional[float] = None
     t_admitted: Optional[float] = None
     t_first_token: Optional[float] = None
     t_finished: Optional[float] = None
@@ -107,6 +108,14 @@ class Request:
             return self.prompt
         return np.concatenate(
             [self.prompt, np.asarray(self.generated, np.int32)])
+
+    @property
+    def t_due(self) -> Optional[float]:
+        """When the request was in the engine and due for admission:
+        the later of its submission and its arrival_time."""
+        if self.t_submitted is None:
+            return None
+        return max(self.t_submitted, self.arrival_time)
 
     @property
     def ttft(self) -> Optional[float]:
