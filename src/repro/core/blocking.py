@@ -88,17 +88,31 @@ def choose_flash_config(
     return FlashBlockConfig(bq=min(256, tq), bk=min(512, tk))
 
 
+def decode_vmem_bytes(bk: int, hkv: int, d: int, itemsize: int) -> int:
+    """Working set of the decode kernel: a (bk, hkv, d) K block and a V
+    block, double-buffered. The per-head query, output and f32 scratch
+    rows are a few KB and are left out."""
+    return 2 * 2 * bk * hkv * d * itemsize
+
+
 def choose_decode_config(
     tk: int,
+    hkv: int,
     d: int,
     itemsize: int = 2,
     chip: hw.ChipSpec = hw.DEFAULT_CHIP,
 ) -> FlashBlockConfig:
-    """Default K/V tile for the q_len=1 decode kernel. The query tile is
-    a single row by construction, so the only knob is how much of the
-    cache streams per grid step; 512 keeps the DMA pipeline deep while
-    the prefix skip (pos < k_start) bounds wasted blocks to one."""
-    return FlashBlockConfig(bq=1, bk=min(512, tk))
+    """Default K/V tile for the q_len=1 decode kernel. One grid step
+    streams bk cache rows of every kv head (bk * hkv * d elements per
+    side), so bk starts at min(512, tk) and halves while K and V,
+    double-buffered, exceed the VMEM budget; it stays a divisor of tk.
+    512 keeps the DMA pipeline deep while the prefix clamp bounds the
+    rows fetched past a slot's depth to one block."""
+    bk = min(512, tk)
+    while (decode_vmem_bytes(bk, hkv, d, itemsize) > vmem_budget(chip)
+           and bk % 2 == 0 and tk % (bk // 2) == 0):
+        bk //= 2
+    return FlashBlockConfig(bq=1, bk=bk)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -340,28 +354,33 @@ def flash_unfused_traffic_bytes(tq: int, tk: int, d: int,
 
 
 def decode_traffic_bytes(pos: int, tk: int, d: int, cfg: FlashBlockConfig,
-                         itemsize: int) -> int:
-    """Fused decode-step traffic per (batch x head): the single query row
-    and output row bracket a K/V stream that covers only the valid cache
-    prefix — the kernel's `k_start <= pos` skip means blocks past the
-    write head are never DMA'd, so a depth-4096 cache at pos=127 moves
-    ceil(128/bk)*bk rows, not 4096."""
+                         itemsize: int, *, h: int = 1,
+                         hkv: int = 1) -> int:
+    """Fused decode-step traffic per slot: the h query and output rows
+    bracket a K/V stream of each kv head's valid cache prefix, read
+    once (every query head of a kv head's group is served from the same
+    block) and rounded up to whole bk blocks. The kernel's index map
+    clamps to the block holding `pos`, so blocks past the write head are
+    never DMA'd: a depth-4096 cache at pos=127 moves ceil(128/bk)*bk
+    rows per kv head, not 4096."""
     n_blocks = math.ceil((pos + 1) / cfg.bk)
-    kv_bytes = 2 * n_blocks * cfg.bk * d * itemsize
-    return kv_bytes + 2 * d * itemsize
+    kv_bytes = 2 * hkv * n_blocks * cfg.bk * d * itemsize
+    return kv_bytes + 2 * h * d * itemsize
 
 
 def decode_unfused_traffic_bytes(pos: int, tk: int, d: int,
-                                 itemsize: int) -> int:
+                                 itemsize: int, *, h: int = 1,
+                                 hkv: int = 1) -> int:
     """The masked-dense decode baseline (chunked/XLA over the whole
-    cache buffer): padding cannot be skipped because the mask is data,
-    so all tk cache rows stream, plus the (1, tk) score row's f32 round
-    trips. `pos` is accepted for signature symmetry — the baseline's
-    traffic does not depend on it, which is exactly the problem."""
+    cache buffer), per slot: padding cannot be skipped because the mask
+    is data, so all tk cache rows of every kv head stream, plus each
+    query head's (1, tk) f32 score row round trips. `pos` is accepted
+    for signature symmetry — the baseline's traffic does not depend on
+    it, which is exactly the problem."""
     del pos
-    kv_bytes = 2 * tk * d * itemsize
-    s_bytes = 4 * tk * 4
-    return kv_bytes + s_bytes + 2 * d * itemsize
+    kv_bytes = 2 * hkv * tk * d * itemsize
+    s_bytes = h * 4 * tk * 4
+    return kv_bytes + s_bytes + 2 * h * d * itemsize
 
 
 def flash_bwd_traffic_bytes(

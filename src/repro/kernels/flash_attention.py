@@ -422,10 +422,20 @@ def flash_attention_bwd(
 # ----------------------------------------------------------------------
 # Decode-specialized kernel (q_len = 1 against a long cache)
 # ----------------------------------------------------------------------
+#
+# The kernel reads the serving cache as it is stored, [B, Tk, Hkv, D],
+# through the free row-major view [B, Tk*Hkv, D]: one grid step (slot,
+# key block) takes a (bk*Hkv, D) block holding bk cache rows of every kv
+# head, so each K/V byte is DMA'd once and serves all `group` query
+# heads of its kv head while it sits in VMEM. Row r of the block is key
+# r // Hkv of kv head r % Hkv; every query head is scored against every
+# row, and the pairs of different kv heads are masked.
+
 
 def _flash_decode_kernel(
     pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-    *, n_kv: int, bk: int, scale: float, window: int | None,
+    *, n_kv: int, bk: int, hkv: int, group: int, scale: float,
+    window: int | None,
 ):
     kv_i = pl.program_id(1)
 
@@ -435,39 +445,41 @@ def _flash_decode_kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    pos = pos_ref[pl.program_id(0)]       # scalar-prefetch, (B*H,) SMEM
+    pos = pos_ref[pl.program_id(0)]       # scalar-prefetch, (B,) SMEM
     k_start = kv_i * bk
 
     # THE decode win: only blocks intersecting the valid prefix
-    # [max(0, pos-window+1), pos] run — a slot at depth 100 in a 4096
-    # cache touches one K/V block, not eight. pos < 0 (inactive slot)
-    # skips every block; the flush's l == 0 guard keeps o finite.
+    # [max(0, pos-window+1), pos] run (and, by the index map's clamp,
+    # only they are fetched) — a slot at depth 100 in a 4096 cache
+    # touches one K/V block, not eight. pos < 0 (inactive slot) skips
+    # every block; the flush's l == 0 guard keeps o finite.
     run = k_start <= pos
     if window is not None:
         run = jnp.logical_and(run, k_start + bk - 1 > pos - window)
 
     @pl.when(run)
     def _body():
-        q = q_ref[0].astype(jnp.float32) * scale          # (1, d)
-        k = k_ref[0].astype(jnp.float32)                  # (bk, d)
-        v = v_ref[0].astype(jnp.float32)                  # (bk, d)
+        q = q_ref[0, 0]                                   # (h, d)
+        k, v = k_ref[0], v_ref[0]                         # (bk*hkv, d)
+        q = q.astype(jnp.promote_types(q.dtype, k.dtype))
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (1, bk)
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        mask = k_pos <= pos                  # kv_len = pos + 1 prefix
+            q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (h, bk*hkv)
+        r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+        k_pos = k_start + r // hkv
+        mask = (r % hkv == head) & (k_pos <= pos)  # kv_len = pos + 1
         if window is not None:
             mask &= k_pos > pos - window
         s = jnp.where(mask, s, _NEG_INF)
 
-        m_prev = m_ref[...]                               # (1, LANES)
-        s_max = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, s_max)
+        m_prev = m_ref[...]                               # (h, LANES)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[:, :1])
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
@@ -475,67 +487,86 @@ def _flash_decode_kernel(
     def _flush():
         l = l_ref[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def decode_kv_block(j, pos, bk: int, window: int | None = None):
+    """The K/V block grid step j of a slot at depth `pos` fetches: j
+    clamped to the blocks that hold keys in (pos - window, pos]. Past
+    them the block index repeats, so the pipeline starts no DMA; an
+    inactive slot (pos < 0) names block 0 only."""
+    last = jnp.maximum(pos, 0) // bk
+    if window is None:
+        return jnp.minimum(j, last)
+    first = jnp.maximum(pos - window + 1, 0) // bk
+    return jnp.clip(j, first, last)
 
 
 def flash_decode(
-    q: jnp.ndarray,           # [B*H, 1, D]  one new token per row
-    k: jnp.ndarray,           # [B*Hkv, Tk, D]  the cache, max_len deep
-    v: jnp.ndarray,           # [B*Hkv, Tk, D]
+    q: jnp.ndarray,           # [B, 1, H, D]  one new token per slot
+    k: jnp.ndarray,           # [B, Tk, Hkv, D]  the cache, max_len deep
+    v: jnp.ndarray,           # [B, Tk, Hkv, D]
     *,
-    group: int = 1,           # H // Hkv
     window: int | None = None,
     scale: float | None = None,
-    pos=0,                    # scalar, or (B*H,) per-row depth vector;
+    pos=0,                    # scalar, or (B,) per-slot depth vector;
                               # valid prefix is keys [0, pos] (causal)
     bk: int = 512,
     block=None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """q_len=1 flash attention. Equivalent to causal flash_attention
-    with q_offset=pos at tq=1, but grid (B*H, Tk/bk) with per-row
-    block-level skip: K/V stream only over the slot's valid prefix
-    instead of the whole max_len cache. GQA reads kv row h // group —
-    kv heads are never repeated. Rows with pos < 0 (inactive slots)
-    produce finite garbage the caller discards."""
+    """q_len=1 flash attention over the cache as stored. Equivalent to
+    causal flash_attention with q_offset=pos at tq=1, on a (B, Tk/bk)
+    grid: each step streams bk rows of every kv head of one slot once,
+    and its index map clamps to the slot's valid prefix, so blocks past
+    it are never fetched. GQA serves the `H // Hkv` query heads of a kv
+    head from the same block — kv heads are never repeated. Returns
+    [B, 1, H, D]; slots with pos < 0 (inactive) produce finite garbage
+    the caller discards."""
     if block is not None:
         bk = block.bk
-    bh, tq, d = q.shape
+    b, tq, h, d = q.shape
     assert tq == 1, f"flash_decode is q_len=1 only, got tq={tq}"
-    bhkv, tk, dk_ = k.shape
-    assert d == dk_ and v.shape == k.shape
-    assert bh == bhkv * group, (bh, bhkv, group)
+    _, tk, hkv, dk_ = k.shape
+    assert k.shape[0] == b and d == dk_ and v.shape == k.shape, \
+        (q.shape, k.shape, v.shape)
+    assert h % hkv == 0, (h, hkv)
     scale = scale if scale is not None else d ** -0.5
     bk = min(bk, tk)
     assert tk % bk == 0, (tk, bk)
     n_kv = tk // bk
 
+    def kv_map(i, j, p):
+        return (i, decode_kv_block(j, p[i], bk, window), 0)
+
+    def row_map(i, j, p):
+        return (i, 0, 0, 0)
+
     return pl.pallas_call(
         functools.partial(
-            _flash_decode_kernel, n_kv=n_kv, bk=bk, scale=scale,
-            window=window),
+            _flash_decode_kernel, n_kv=n_kv, bk=bk, hkv=hkv,
+            group=h // hkv, scale=scale, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, n_kv),
+            grid=(b, n_kv),
             in_specs=[
-                pl.BlockSpec((1, 1, d), lambda h, j, p: (h, 0, 0)),
-                pl.BlockSpec((1, bk, d),
-                             lambda h, j, p, g=group: (h // g, j, 0)),
-                pl.BlockSpec((1, bk, d),
-                             lambda h, j, p, g=group: (h // g, j, 0)),
+                pl.BlockSpec((1, 1, h, d), row_map),
+                pl.BlockSpec((1, bk * hkv, d), kv_map),
+                pl.BlockSpec((1, bk * hkv, d), kv_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, d), lambda h, j, p: (h, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, h, d), row_map),
             scratch_shapes=[
-                pltpu.VMEM((1, d), jnp.float32),
-                pltpu.VMEM((1, _LANES), jnp.float32),
-                pltpu.VMEM((1, _LANES), jnp.float32),
+                pltpu.VMEM((h, d), jnp.float32),
+                pltpu.VMEM((h, _LANES), jnp.float32),
+                pltpu.VMEM((h, _LANES), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
         interpret=interpret,
         name="flash_decode",
         compiler_params=compiler_params("parallel", "arbitrary"),
-    )(_row_offsets(pos, bh), q, k, v)
+    )(_row_offsets(pos, b), q, k.reshape(b, tk * hkv, d),
+      v.reshape(b, tk * hkv, d))
 
 
 # ----------------------------------------------------------------------

@@ -588,17 +588,19 @@ def _flash_decode_xla(q, k, v, *, policy, pos, window, bk, block):
 
 @register_op("flash_decode", backend="pallas")
 def _flash_decode_pallas(q, k, v, *, policy, pos, window, bk, block):
-    b_, tq, h, d = q.shape
-    _, tk, hkv, _ = k.shape
+    _, tk, hkv, d = k.shape
     if block is None and policy.autotune == "cached":
-        block = _tcache.get_cache().get_flash_decode(tk, d, q.dtype, policy)
-    if block is not None:
+        block = _tcache.get_cache().get_flash_decode(tk, hkv, d, q.dtype,
+                                                     policy)
+    if block is None:
+        # the caller's bk is a ceiling; the chooser keeps the (bk, hkv, d)
+        # K/V blocks inside the VMEM budget
+        bk = min(bk, blocking.choose_decode_config(
+            tk, hkv, d, jnp.dtype(k.dtype).itemsize, policy.chip).bk)
+    else:
         bk = block.bk
-    o = _fa.flash_decode(
-        _flat_heads(q), _flat_heads(k), _flat_heads(v),
-        group=h // hkv, window=window, pos=_per_head(pos, h), bk=bk,
-        interpret=policy.resolved_interpret)
-    return o.reshape(b_, h, tq, d).transpose(0, 2, 1, 3)
+    return _fa.flash_decode(q, k, v, window=window, pos=pos, bk=bk,
+                            interpret=policy.resolved_interpret)
 
 
 def flash_decode(

@@ -230,17 +230,20 @@ def attention_fwd_savings(tq: int, tk: int, d: int, itemsize: int,
 
 def decode_attention_savings(pos: int, tk: int, d: int, itemsize: int,
                              cfg: blocking.FlashBlockConfig | None = None,
-                             chip: hw.ChipSpec = hw.DEFAULT_CHIP) -> dict:
+                             chip: hw.ChipSpec = hw.DEFAULT_CHIP, *,
+                             h: int = 1, hkv: int = 1) -> dict:
     """Fractional HBM-byte saving of the decode kernel over the masked
-    dense scan, per (batch x head) — the number
+    dense scan, per slot of h query and hkv kv heads — the number
     benchmarks/bench_flash_attention.py asserts. Two independent terms:
-    the prefix skip (only ceil((pos+1)/bk)*bk of tk cache rows stream,
-    the dominant win early in a long-max-length cache) and the skipped
-    (1, tk) f32 score-row round trips."""
+    the prefix clamp (only ceil((pos+1)/bk)*bk of tk cache rows of each
+    kv head stream, the dominant win early in a long-max-length cache)
+    and the skipped (1, tk) f32 score-row round trips."""
     if cfg is None:
-        cfg = blocking.choose_decode_config(tk, d, itemsize, chip=chip)
-    fused = blocking.decode_traffic_bytes(pos, tk, d, cfg, itemsize)
-    unfused = blocking.decode_unfused_traffic_bytes(pos, tk, d, itemsize)
+        cfg = blocking.choose_decode_config(tk, hkv, d, itemsize, chip=chip)
+    fused = blocking.decode_traffic_bytes(pos, tk, d, cfg, itemsize,
+                                          h=h, hkv=hkv)
+    unfused = blocking.decode_unfused_traffic_bytes(pos, tk, d, itemsize,
+                                                    h=h, hkv=hkv)
     return {"fused_bytes": fused, "unfused_bytes": unfused,
             "saved_frac": 1.0 - fused / unfused, "cfg": cfg}
 

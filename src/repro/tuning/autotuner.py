@@ -301,6 +301,7 @@ def tune_flash_decode(
     *,
     batch: int = 4,
     heads: int = 1,
+    kv_heads: int | None = None,
     pos: int | None = None,
     window: int | None = None,
     policy: Policy | None = None,
@@ -314,7 +315,8 @@ def tune_flash_decode(
     seed: int = 0,
 ) -> TuneResult:
     """Sweep K/V tile sizes for the q_len=1 decode kernel over a
-    depth-tk cache and persist the winner under flash_decode_key.
+    depth-tk cache of `kv_heads` kv heads (default: `heads`) and persist
+    the winner under flash_decode_key.
 
     `pos` defaults to tk - 1 (a full cache): that is the worst case for
     DMA volume and the regime the steady-state serving loop lives in, so
@@ -327,21 +329,23 @@ def tune_flash_decode(
     chip = pol.chip
     cache = get_cache() if cache is None else cache
     interpret = pol.resolved_interpret
+    hkv = heads if kv_heads is None else kv_heads
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(batch, 1, heads, d)), dtype)
-    kv = jnp.asarray(rng.normal(size=(batch, tk, heads, d)), dtype)
+    kv = jnp.asarray(rng.normal(size=(batch, tk, hkv, d)), dtype)
     pos_v = jnp.full((batch,), tk - 1 if pos is None else pos, jnp.int32)
     itemsize = jnp.dtype(dtype).itemsize
 
     return _sweep(
-        "flash_decode", f"flash_decode {tk}xd{d} {np.dtype(dtype).name}",
-        _space.flash_decode_candidates(tk, d, itemsize, chip=chip,
+        "flash_decode",
+        f"flash_decode {tk}xh{hkv}xd{d} {np.dtype(dtype).name}",
+        _space.flash_decode_candidates(tk, hkv, d, itemsize, chip=chip,
                                        max_candidates=max_candidates),
         lambda cfg: _timer(lambda x, y, p, c=cfg: _ops.flash_decode(
             x, y, y, pos=p, window=window, policy=pol, block=c),
             (q, kv, pos_v), interpret, warmup, iters),
-        lambda cfg, meta: cache.put_flash_decode(tk, d, dtype, pol, cfg,
-                                                 **meta),
+        lambda cfg, meta: cache.put_flash_decode(tk, hkv, d, dtype, pol,
+                                                 cfg, **meta),
         cache, save, pol.kernel_fingerprint)
 
 
@@ -540,9 +544,11 @@ def model_attention_shapes(cfg, batch: int, seq: int,
     """The flash-kernel shapes a (batch, seq) step of `cfg` runs, as
     deduplicated ``(op, tq, tk, d, "-")`` entries — op "flash" (fused
     forward), "flash_bwd" (training backward, with backward=True) or
-    "flash_decode" (``(op, 1, decode_len, d, "-")``, when a cache depth
-    is given). Entries mirror model_gemm_shapes' 5-tuple layout so
-    warm_start can interleave the two lists in one report.
+    "flash_decode" (``(op, n_kv_heads, decode_len, d, "-")``, when a
+    cache depth is given: its query length is 1 by construction, and its
+    tile is keyed by the kv heads each grid step streams). Entries
+    mirror model_gemm_shapes' 5-tuple layout so warm_start can
+    interleave the two lists in one report.
 
     Attention shapes are per (batch x head) slice, so `batch` does not
     enter the keys — it is accepted for signature symmetry. Pure-SSM
@@ -559,7 +565,8 @@ def model_attention_shapes(cfg, batch: int, seq: int,
         if backward:
             entries.add(("flash_bwd", seq, seq, head_dim, "-"))
     if decode_len:
-        entries.add(("flash_decode", 1, decode_len, head_dim, "-"))
+        entries.add(("flash_decode", cfg.n_kv_heads, decode_len, head_dim,
+                     "-"))
     return sorted(entries)
 
 
@@ -683,7 +690,7 @@ def warm_start(
         elif op == "flash_bwd":
             hit = cache.get_flash_bwd(m, n, k, dtype, pol) is not None
         elif op == "flash_decode":
-            hit = cache.get_flash_decode(n, k, dtype, pol) is not None
+            hit = cache.get_flash_decode(n, m, k, dtype, pol) is not None
         elif op == "ssd":
             hit = cache.get_ssd(m, n, k, dtype, pol) is not None
         else:
@@ -709,7 +716,8 @@ def warm_start(
                                    max_candidates=max_candidates,
                                    save=False)
                 elif op == "flash_decode":
-                    tune_flash_decode(n, k, dtype, policy=pol,
+                    tune_flash_decode(n, k, dtype, kv_heads=m,
+                                      heads=cfg.n_heads, policy=pol,
                                       cache=cache, iters=iters,
                                       max_candidates=max_candidates,
                                       save=False)

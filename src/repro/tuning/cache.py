@@ -104,11 +104,12 @@ def flash_key(tq: int, tk: int, d: int, dtype, backend) -> str:
     return f"flash|{tq}x{tk}xd{d}|{np.dtype(dtype).name}|{_backend_tag(backend)}"
 
 
-def flash_decode_key(tk: int, d: int, dtype, backend) -> str:
-    """The decode kernel is q_len=1 by construction, so its shape key is
-    just (cache depth, head dim) — every slot depth shares one entry
-    (pos streams as data, not a trace constant)."""
-    return (f"flash_decode|{tk}xd{d}|{np.dtype(dtype).name}|"
+def flash_decode_key(tk: int, hkv: int, d: int, dtype, backend) -> str:
+    """The decode kernel is q_len=1 by construction and streams bk rows
+    of every kv head per grid step, so its shape key is (cache depth, kv
+    heads, head dim) — every slot depth shares one entry (pos streams as
+    data, not a trace constant)."""
+    return (f"flash_decode|{tk}xh{hkv}xd{d}|{np.dtype(dtype).name}|"
             f"{_backend_tag(backend)}")
 
 
@@ -272,16 +273,16 @@ class TuningCache:
         self.put(key, {"bq": cfg.bq, "bk": cfg.bk, "tuned_at": _now(), **meta})
         return key
 
-    def get_flash_decode(self, tk: int, d: int, dtype,
+    def get_flash_decode(self, tk: int, hkv: int, d: int, dtype,
                          backend) -> Optional[FlashBlockConfig]:
-        e = self.get(flash_decode_key(tk, d, dtype, backend))
+        e = self.get(flash_decode_key(tk, hkv, d, dtype, backend))
         if e is None:
             return None
         return FlashBlockConfig(bq=1, bk=int(e["bk"]))
 
-    def put_flash_decode(self, tk: int, d: int, dtype, backend,
+    def put_flash_decode(self, tk: int, hkv: int, d: int, dtype, backend,
                          cfg: FlashBlockConfig, **meta: Any) -> str:
-        key = flash_decode_key(tk, d, dtype, backend)
+        key = flash_decode_key(tk, hkv, d, dtype, backend)
         self.put(key, {"bk": cfg.bk, "tuned_at": _now(), **meta})
         return key
 

@@ -127,6 +127,7 @@ def flash_candidates(
 
 def flash_decode_candidates(
     tk: int,
+    hkv: int,
     d: int,
     itemsize: int,
     chip: hw.ChipSpec = hw.DEFAULT_CHIP,
@@ -135,22 +136,22 @@ def flash_decode_candidates(
 ) -> list[FlashBlockConfig]:
     """Feasible K/V tiles for the q_len=1 decode kernel. bq is pinned to
     1 by construction, so the space is one-dimensional: bk divisors of
-    the cache depth. Larger bk deepens the DMA pipeline but coarsens the
-    prefix skip (a near-empty cache still streams one full block), which
-    is exactly the trade the timer should settle."""
+    the cache depth whose (bk, hkv, d) K and V blocks fit VMEM. Larger
+    bk deepens the DMA pipeline but coarsens the prefix clamp (a
+    near-empty cache still streams one full block), which is exactly
+    the trade the timer should settle."""
     budget = int(chip.vmem_bytes * vmem_fraction)
-    default = blocking.choose_decode_config(tk, d, itemsize, chip=chip)
+    default = blocking.choose_decode_config(tk, hkv, d, itemsize, chip=chip)
     out = [default]
     seen = {default.bk}
     for bk in _FBK:
         bk = min(bk, tk)
         if tk % bk or bk in seen:
             continue
-        cfg = FlashBlockConfig(1, bk)
-        if cfg.vmem_bytes(d, itemsize) > budget:
+        if blocking.decode_vmem_bytes(bk, hkv, d, itemsize) > budget:
             continue
         seen.add(bk)
-        out.append(cfg)
+        out.append(FlashBlockConfig(1, bk))
     if max_candidates is not None:
         out = out[:max(1, max_candidates)]
     return out

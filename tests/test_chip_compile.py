@@ -12,6 +12,7 @@ only the worker that runs this file loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,6 +97,63 @@ def test_flash_decode_compiles(one_chip):
         q, k, v, pos=pos, policy=PALLAS), one_chip,
         ((4, 1, 16, 128), jnp.bfloat16), ((4, 2048, 8, 128), jnp.bfloat16),
         ((4, 2048, 8, 128), jnp.bfloat16), ((4,), jnp.int32))
+
+
+def _cache_consumers(text, params):
+    """The ops of the compiled module's entry computation that read the
+    named parameters, following bitcasts (which move no bytes)."""
+    entry = text[text.index("ENTRY"):].split("\n}")[0]
+    defs = re.findall(r"^\s*(?:ROOT )?%(\S+) = .*?\s([\w-]+)\((.*)$",
+                      entry, re.M)
+    names = {n for n, op, args in defs
+             if op == "parameter" and re.match(r"(\d+)\)", args)
+             and int(re.match(r"(\d+)\)", args).group(1)) in params}
+    readers, grew = [], True
+    while grew:
+        grew = False
+        for n, op, args in defs:
+            if n in names or not any(f"%{m}" in re.findall(r"%[\w.\-]+", args)
+                                     for m in names):
+                continue
+            if op == "bitcast":
+                names.add(n)
+                grew = True
+            elif (n, op) not in readers:
+                readers.append((n, op))
+    return readers
+
+
+@pytest.mark.parametrize("b,tk,h,hkv,d", [
+    (24, 2560, 16, 8, 128),           # qwen3-0.6b chat: 24 slots x 2560
+    (4, 2048, 12, 2, 128),            # qwen2-vl-2b
+    (4, 2048, 48, 1, 128),            # granite-20b (one kv head)
+    (4, 2048, 40, 40, 128),           # qwen1.5-32b (MHA)
+])
+def test_flash_decode_reads_cache_in_place(one_chip, b, tk, h, hkv, d):
+    """The decode kernel takes K and V as the cache stores them: the
+    only op of the compiled module that reads either is the kernel
+    (through free bitcasts), so no copy or transpose of the cache runs
+    per step."""
+    text = _compile(lambda q, k, v, pos: ops.flash_decode(
+        q, k, v, pos=pos, policy=PALLAS), one_chip,
+        ((b, 1, h, d), jnp.bfloat16), ((b, tk, hkv, d), jnp.bfloat16),
+        ((b, tk, hkv, d), jnp.bfloat16), ((b,), jnp.int32))
+    readers = _cache_consumers(text, params={1, 2})
+    assert readers and all(op == "custom-call" for _, op in readers), \
+        readers
+
+
+@pytest.mark.parametrize("b,tk,h,hkv,d", [
+    (4, 448, 6, 6, 64),               # whisper-tiny self-attention
+    (4, 2048, 32, 32, 64),            # zamba2-1.2b shared attention
+])
+def test_flash_decode_head_dim_64_compiles(one_chip, b, tk, h, hkv, d):
+    # at 64-lane rows the compiled module relayouts K and V (a copy)
+    # before the kernel, where the old kernel's operand transpose was
+    _compile(lambda q, k, v, pos: ops.flash_decode(
+        q, k, v, pos=pos, policy=PALLAS), one_chip,
+        ((b, 1, h, d), jnp.bfloat16), ((b, tk, hkv, d), jnp.bfloat16),
+        ((b, tk, hkv, d), jnp.bfloat16), ((b,), jnp.int32))
 
 
 _POOL = (512, 8, 16, 128)             # [P, Hkv, page_size, D]

@@ -197,17 +197,68 @@ def test_ragged_shapes_fall_back_chunked_and_differentiate(rng):
 # decode kernel
 # ----------------------------------------------------------------------
 
-@pytest.mark.parametrize("window", [None, 24])
-def test_flash_decode_vs_ref(rng, window):
-    """Ragged per-slot depths against the dense oracle, window incl."""
-    b, tk = 3, 128
-    q, k, v = _qkv(rng, b, 1, tk, 4, 2, 32)
-    pos = jnp.asarray([tk - 1, 37, 0], jnp.int32)
-    out = ops.flash_decode(q, k, v, pos=pos, window=window, policy=_PI)
-    ref, _ = attention_fwd_ref(q, k, v, causal=True, window=window,
+# Slot depths around a block edge at bk=32 in a 128-deep cache: the
+# block's last key, the next block's first, a full cache, the first key
+# and an inactive slot.
+_EDGES = [31, 32, 127, 0, -1]
+
+
+@pytest.mark.parametrize("h,hkv,d,window,pos,bk,dtype", [
+    pytest.param(4, 2, 32, None, [127, 37, 0], 512, "float32", id="None"),
+    pytest.param(4, 2, 32, 24, [127, 37, 0], 512, "float32", id="24"),
+    pytest.param(4, 4, 64, None, _EDGES, 32, "float32", id="group1-d64"),
+    pytest.param(4, 2, 128, None, _EDGES, 32, "float32", id="group2-d128"),
+    pytest.param(4, 1, 64, None, _EDGES, 32, "float32", id="groupH-d64"),
+    pytest.param(4, 1, 128, None, _EDGES, 32, "float32", id="groupH-d128"),
+    pytest.param(4, 2, 128, 40, _EDGES, 32, "float32",
+                 id="group2-d128-window"),
+    pytest.param(4, 4, 64, 40, _EDGES, 32, "float32", id="group1-d64-window"),
+    pytest.param(4, 2, 128, None, 77, 32, "float32", id="scalar-pos"),
+    pytest.param(4, 4, 128, None, _EDGES, 32, "bfloat16",
+                 id="group1-d128-bf16"),
+    pytest.param(4, 2, 128, 40, _EDGES, 32, "bfloat16",
+                 id="group2-d128-window-bf16"),
+    pytest.param(6, 3, 128, 40, _EDGES, 32, "bfloat16",
+                 id="odd-kv-heads-d128-window-bf16"),
+    pytest.param(4, 1, 128, None, 77, 32, "bfloat16",
+                 id="groupH-d128-scalar-pos-bf16"),
+])
+def test_flash_decode_vs_ref(rng, h, hkv, d, window, pos, bk, dtype):
+    """Per-slot depths (ragged, around block edges, inactive) and scalar
+    depths against the dense oracle: one kv head per query head, a
+    shared kv head, a single kv head, an odd kv-head count, head dims
+    under and at 128 lanes, 32- and 16-bit caches, windows."""
+    tk = 128
+    b = len(pos) if isinstance(pos, list) else 2
+    q, k, v = _qkv(rng, b, 1, tk, h, hkv, d, dtype)
+    pos = jnp.asarray(pos, jnp.int32)
+    out = ops.flash_decode(q, k, v, pos=pos, window=window, policy=_PI,
+                           bk=bk)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref, _ = attention_fwd_ref(*f32, causal=True, window=window,
                                q_offset=pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
+    tol = 2e-4 if dtype == "float32" else 3e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("window", [None, 40])
+def test_flash_decode_index_map_stays_in_prefix(window):
+    """The K/V index map, fed the prefetched depths, never names a block
+    past a slot's depth or before its window, names every block the
+    slot attends to, and names block 0 alone for an inactive slot — so
+    the pipeline fetches each valid block once and nothing else."""
+    from repro.kernels.flash_attention import decode_kv_block
+    bk, n_kv = 32, 8
+    for pos in [-1, 0, 31, 32, 100, 255]:
+        named = [int(decode_kv_block(jnp.int32(j), jnp.int32(pos), bk,
+                                     window)) for j in range(n_kv)]
+        if pos < 0:
+            assert set(named) == {0}
+            continue
+        lo = 0 if window is None else max(pos - window + 1, 0) // bk
+        assert set(named) == set(range(lo, pos // bk + 1)), (pos, named)
+        assert named == sorted(named)      # repeats only: no re-fetch
 
 
 def test_flash_decode_inactive_slot_is_finite_zero(rng):

@@ -109,6 +109,30 @@ def test_report_bounds_and_terms():
     assert rep.t_collective == 0.0              # no collectives on 1 dev
 
 
+def test_decode_traffic_reads_each_kv_head_prefix_once():
+    from repro.core.blocking import (FlashBlockConfig, choose_decode_config,
+                                     decode_traffic_bytes)
+    from repro.roofline.analysis import decode_attention_savings
+    cfg = FlashBlockConfig(bq=1, bk=512)
+    # one slot of qwen3-0.6b (16 query heads over 8 kv heads, d 128):
+    # each kv head's prefix once, in whole blocks, plus q and o rows
+    rows = lambda pos: decode_traffic_bytes(pos, 2560, 128, cfg, 2, h=16,
+                                            hkv=8) - 2 * 16 * 128 * 2
+    assert rows(0) == rows(511) == 2 * 8 * 512 * 128 * 2
+    assert rows(512) == 2 * rows(511)
+    assert rows(2559) == 2 * 8 * 2560 * 128 * 2        # full cache
+    # the group does not re-read K/V: twice the query heads, same bytes
+    assert decode_traffic_bytes(127, 2560, 128, cfg, 2, h=32, hkv=8) \
+        - decode_traffic_bytes(127, 2560, 128, cfg, 2, h=16, hkv=8) \
+        == 2 * 16 * 128 * 2
+    # the chooser keeps a power-of-two divisor of the depth, at most 512
+    assert choose_decode_config(2560, 8, 128, 2).bk == 512
+    assert choose_decode_config(96, 8, 128, 2).bk == 96
+    # early in a long cache the prefix clamp is the win
+    s = decode_attention_savings(127, 4096, 128, 2, h=16, hkv=8)
+    assert s["saved_frac"] >= 0.80, s
+
+
 def test_kv_traffic_and_quant_savings_thresholds():
     from repro.roofline.analysis import kv_decode_traffic_bytes, \
         kv_quant_savings

@@ -174,11 +174,14 @@ def test_model_attention_shapes_skips_ssm():
 
 
 def test_flash_decode_candidates_divide_cache():
-    cands = space.flash_decode_candidates(2048, 64, itemsize=2)
+    cands = space.flash_decode_candidates(2048, 8, 64, itemsize=2)
     assert all(c.bq == 1 and 2048 % c.bk == 0 for c in cands)
     assert len({c.bk for c in cands}) == len(cands)
     from repro.core import blocking
-    assert cands[0] == blocking.choose_decode_config(2048, 64, 2)
+    assert cands[0] == blocking.choose_decode_config(2048, 8, 64, 2)
+    # every candidate's (bk, hkv, d) K and V blocks fit the VMEM budget
+    assert all(blocking.decode_vmem_bytes(c.bk, 8, 64, 2)
+               <= blocking.vmem_budget() for c in cands)
 
 
 def test_flash_bwd_candidates_feasible():
@@ -190,12 +193,13 @@ def test_flash_bwd_candidates_feasible():
 def test_tune_flash_decode_populates_cache(tmp_cache):
     pol_fp = "pallas_interpret"
     res = autotuner.tune_flash_decode(256, 32, "float32", backend=pol_fp,
-                                      batch=2, warmup=0, iters=1,
-                                      max_candidates=2)
+                                      batch=2, heads=4, kv_heads=2,
+                                      warmup=0, iters=1, max_candidates=2)
     assert res.best_s > 0 and res.best.bq == 1
-    served = tcache.TuningCache(tmp_cache).load().get_flash_decode(
-        256, 32, "float32", pol_fp)
-    assert served == res.best
+    served = tcache.TuningCache(tmp_cache).load()
+    assert served.get_flash_decode(256, 2, 32, "float32", pol_fp) == res.best
+    # the winner is keyed by its kv heads: another head count misses
+    assert served.get_flash_decode(256, 1, 32, "float32", pol_fp) is None
 
 
 def test_tune_flash_bwd_populates_cache(tmp_cache):
