@@ -11,7 +11,7 @@ from repro.configs.base import ModelConfig, MoEConfig, SSMConfig, SHAPES, get_sh
 from repro.configs import (
     whisper_tiny, mixtral_8x22b, arctic_480b, qwen2_vl_2b, qwen3_0_6b,
     qwen1_5_32b, granite_20b, granite_3_8b, zamba2_1_2b, mamba2_2_7b,
-    paper_gemm,
+    granite_4_0_h_micro, paper_gemm,
 )
 
 _REGISTRY = {
@@ -19,6 +19,7 @@ _REGISTRY = {
     for m in (
         whisper_tiny, mixtral_8x22b, arctic_480b, qwen2_vl_2b, qwen3_0_6b,
         qwen1_5_32b, granite_20b, granite_3_8b, zamba2_1_2b, mamba2_2_7b,
+        granite_4_0_h_micro,
     )
 }
 

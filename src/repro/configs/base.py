@@ -65,6 +65,18 @@ class ModelConfig:
     ssm: Optional[SSMConfig] = None
     attn_every: int = 0             # hybrid: shared attn block period (Zamba2)
     shared_attn_lora_rank: int = 0  # Zamba2 per-invocation LoRA on shared block
+    # hybrid: the mixer of each layer ("mamba" | "attention"), each layer
+    # with its own weights and a SwiGLU MLP after its mixer (Granite 4.0-H);
+    # a JSON list is accepted and kept as a tuple. Empty = not interleaved.
+    layer_types: Tuple[str, ...] = ()
+    # Granite-style multipliers; the defaults leave the computation as it
+    # is for every other model
+    norm_eps: float = 1e-6                  # RMSNorm epsilon (ln keeps 1e-5)
+    embedding_multiplier: float = 1.0       # embeddings scaled on lookup
+    # softmax scale; None = head_dim^-0.5
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0        # each branch scaled before its add
+    logits_scaling: float = 1.0             # logits divided by this
     # encoder-decoder (Whisper)
     n_enc_layers: int = 0
     enc_ctx: int = 0                # encoder frames (stub frontend output)
@@ -94,6 +106,24 @@ class ModelConfig:
     max_position: int = 1 << 20
     # activation attention chunking (XLA online-softmax path)
     attn_chunk: int = 2048
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            if len(self.layer_types) != self.n_layers:
+                raise ValueError(f"{len(self.layer_types)} layer_types for "
+                                 f"{self.n_layers} layers")
+            bad = set(self.layer_types) - {"mamba", "attention"}
+            if bad:
+                raise ValueError(f"unknown layer types {sorted(bad)}")
+
+    @property
+    def layer_period(self) -> int:
+        """Shortest period of layer_types that divides the depth: the
+        interleaved stack scans over whole periods."""
+        lt = self.layer_types
+        return next(p for p in range(1, len(lt) + 1)
+                    if len(lt) % p == 0 and lt == lt[:p] * (len(lt) // p))
 
     @property
     def resolved_head_dim(self) -> int:
@@ -145,6 +175,11 @@ class ModelConfig:
             kw["enc_ctx"] = 32
         if self.attn_every:
             kw["attn_every"] = 2
+        if self.layer_types:
+            # one layer of each kind, in order of first appearance, twice
+            kinds = tuple(dict.fromkeys(self.layer_types))
+            kw["layer_types"] = kinds * 2
+            kw["n_layers"] = 2 * len(kinds)
         if self.window is not None:
             kw["window"] = 32
         return dataclasses.replace(self, **kw)

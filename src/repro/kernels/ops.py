@@ -423,14 +423,16 @@ def sub(a, b, *, policy: Policy | None = None, backend: str | None = None,
 # ----------------------------------------------------------------------
 
 @register_op("flash_attention", backend="xla")
-def _flash_xla(q, k, v, *, policy, causal, window, q_offset, bq, bk, block):
+def _flash_xla(q, k, v, *, policy, causal, window, q_offset, bq, bk, block,
+               scale):
     return _ref.attention_ref(
-        q, k, v, causal=causal, window=window, q_offset=q_offset)
+        q, k, v, causal=causal, window=window, scale=scale,
+        q_offset=q_offset)
 
 
 @register_op("flash_attention", backend="pallas")
 def _flash_pallas(q, k, v, *, policy, causal, window, q_offset, bq, bk,
-                  block):
+                  block, scale):
     b_, tq, h, d = q.shape
     _, tk, hkv, _ = k.shape
     if jnp.asarray(q_offset).ndim == 1:
@@ -445,7 +447,7 @@ def _flash_pallas(q, k, v, *, policy, causal, window, q_offset, bq, bk,
     kf = k.transpose(0, 2, 1, 3).reshape(b_ * hkv, tk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(b_ * hkv, tk, d)
     o = _fa.flash_attention(
-        qf, kf, vf, group=g, causal=causal, window=window,
+        qf, kf, vf, group=g, causal=causal, window=window, scale=scale,
         q_offset=q_offset, bq=bq, bk=bk,
         interpret=policy.resolved_interpret)
     return o.reshape(b_, h, tq, d).transpose(0, 2, 1, 3)
@@ -464,12 +466,13 @@ def flash_attention(
     bq: int = 256,
     bk: int = 512,
     block: blocking.FlashBlockConfig | None = None,
+    scale: float | None = None,        # softmax scale; None = D^-0.5
 ) -> jnp.ndarray:
     """Layout-normalising wrapper: model code uses [B, T, H, D]."""
     pol = _policy.resolve(policy, backend)
     impl = _registry.get_impl("flash_attention", pol.backend)
     return impl(q, k, v, policy=pol, causal=causal, window=window,
-                q_offset=q_offset, bq=bq, bk=bk, block=block)
+                q_offset=q_offset, bq=bq, bk=bk, block=block, scale=scale)
 
 
 def _flat_heads(x):
@@ -499,6 +502,7 @@ def flash_attention_fwd(
     bq: int = 256,
     bk: int = 512,
     block: blocking.FlashBlockConfig | None = None,
+    scale: float | None = None,
 ):
     """Forward with residuals: (o, lse[B, H, Tq] f32) — what the
     attention custom-VJP saves for flash_attention_bwd. Not a separate
@@ -507,7 +511,8 @@ def flash_attention_fwd(
     pol = _policy.resolve(policy, backend)
     if pol.backend != "pallas":
         return _ref.attention_fwd_ref(
-            q, k, v, causal=causal, window=window, q_offset=q_offset)
+            q, k, v, causal=causal, window=window, scale=scale,
+            q_offset=q_offset)
     b_, tq, h, d = q.shape
     _, tk, hkv, _ = k.shape
     if block is None and pol.autotune == "cached":
@@ -516,7 +521,7 @@ def flash_attention_fwd(
         bq, bk = block.bq, block.bk
     o, lse = _fa.flash_attention(
         _flat_heads(q), _flat_heads(k), _flat_heads(v),
-        group=h // hkv, causal=causal, window=window,
+        group=h // hkv, causal=causal, window=window, scale=scale,
         q_offset=_per_head(q_offset, h), bq=bq, bk=bk,
         interpret=pol.resolved_interpret, return_lse=True)
     return (o.reshape(b_, h, tq, d).transpose(0, 2, 1, 3),
@@ -525,15 +530,15 @@ def flash_attention_fwd(
 
 @register_op("flash_attention_bwd", backend="xla")
 def _flash_bwd_xla(q, k, v, o, do, lse, *, policy, causal, window,
-                   q_offset, block):
+                   q_offset, block, scale):
     return _ref.attention_bwd_ref(
-        q, k, v, o, do, lse, causal=causal, window=window,
+        q, k, v, o, do, lse, causal=causal, window=window, scale=scale,
         q_offset=q_offset)
 
 
 @register_op("flash_attention_bwd", backend="pallas")
 def _flash_bwd_pallas(q, k, v, o, do, lse, *, policy, causal, window,
-                      q_offset, block):
+                      q_offset, block, scale):
     b_, tq, h, d = q.shape
     _, tk, hkv, _ = k.shape
     g = h // hkv
@@ -542,7 +547,7 @@ def _flash_bwd_pallas(q, k, v, o, do, lse, *, policy, causal, window,
     dq, dk, dv = _fa.flash_attention_bwd(
         _flat_heads(q), _flat_heads(k), _flat_heads(v),
         _flat_heads(o), _flat_heads(do), lse.reshape(b_ * h, tq),
-        group=g, causal=causal, window=window,
+        group=g, causal=causal, window=window, scale=scale,
         q_offset=_per_head(q_offset, h), block=block,
         interpret=policy.resolved_interpret)
     dq = dq.reshape(b_, h, tq, d).transpose(0, 2, 1, 3)
@@ -567,27 +572,29 @@ def flash_attention_bwd(
     policy: Policy | None = None,
     backend: str | None = None,
     block: blocking.FlashBlockConfig | None = None,
+    scale: float | None = None,
 ):
     """Recompute-style attention backward: (dq, dk, dv) from the saved
     (o, lse) residuals — S/P never hit HBM on the pallas backend."""
     pol = _policy.resolve(policy, backend)
     impl = _registry.get_impl("flash_attention_bwd", pol.backend)
     return impl(q, k, v, o, do, lse, policy=pol, causal=causal,
-                window=window, q_offset=q_offset, block=block)
+                window=window, q_offset=q_offset, block=block, scale=scale)
 
 
 @register_op("flash_decode", backend="xla")
-def _flash_decode_xla(q, k, v, *, policy, pos, window, bk, block):
+def _flash_decode_xla(q, k, v, *, policy, pos, window, bk, block, scale):
     # the fwd_ref composition (not attention_ref): its exp(S - lse) form
     # zeroes fully-masked rows, so inactive slots (pos < 0) agree with
     # the kernel's zero output instead of softmaxing over -1e30 logits.
     o, _ = _ref.attention_fwd_ref(
-        q, k, v, causal=True, window=window, q_offset=pos)
+        q, k, v, causal=True, window=window, scale=scale, q_offset=pos)
     return o
 
 
 @register_op("flash_decode", backend="pallas")
-def _flash_decode_pallas(q, k, v, *, policy, pos, window, bk, block):
+def _flash_decode_pallas(q, k, v, *, policy, pos, window, bk, block,
+                         scale):
     _, tk, hkv, d = k.shape
     if block is None and policy.autotune == "cached":
         block = _tcache.get_cache().get_flash_decode(tk, hkv, d, q.dtype,
@@ -600,6 +607,7 @@ def _flash_decode_pallas(q, k, v, *, policy, pos, window, bk, block):
     else:
         bk = block.bk
     return _fa.flash_decode(q, k, v, window=window, pos=pos, bk=bk,
+                            scale=scale,
                             interpret=policy.resolved_interpret)
 
 
@@ -614,6 +622,7 @@ def flash_decode(
     backend: str | None = None,
     bk: int = 512,
     block: blocking.FlashBlockConfig | None = None,
+    scale: float | None = None,        # softmax scale; None = D^-0.5
 ) -> jnp.ndarray:
     """Decode-specialized attention: each slot's query attends its
     cache prefix [0, pos] (kv_len = pos + 1). The pallas backend streams
@@ -623,7 +632,7 @@ def flash_decode(
     pol = _policy.resolve(policy, backend)
     impl = _registry.get_impl("flash_decode", pol.backend)
     return impl(q, k, v, policy=pol, pos=pos, window=window, bk=bk,
-                block=block)
+                block=block, scale=scale)
 
 
 @register_op("flash_decode_paged", backend="xla")

@@ -57,11 +57,12 @@ def chunked_attention(
     q_offset=0,                   # int / traced scalar / (B,) vector (decode)
     kv_len=None,                  # valid-length mask: scalar or (B,) vector
     io_dtype=jnp.float32,         # bf16 = flash-kernel numerics (§Perf)
+    scale: Optional[float] = None,  # softmax scale; None = D^-0.5
 ) -> jnp.ndarray:
     b, tq, h, d = q.shape
     _, tk, hkv, _ = k.shape
     g = h // hkv
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     chunk = min(chunk, tk)
     assert tk % chunk == 0, (tk, chunk)
     n_chunks = tk // chunk
@@ -165,26 +166,27 @@ def _flash_shapes_ok(tq: int, tk: int) -> bool:
     return tq % min(256, tq) == 0 and tk % min(512, tk) == 0
 
 
-# The fused custom-VJP chokepoint. causal/window/policy ride as nondiff
-# arguments (hashable — the core.gemm pattern), so the backward op runs
-# under the same execution policy as the forward.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _attention_fused(q, k, v, causal, window, pol):
+# The fused custom-VJP chokepoint. causal/window/policy/scale ride as
+# nondiff arguments (hashable — the core.gemm pattern), so the backward
+# op runs under the same execution policy as the forward.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _attention_fused(q, k, v, causal, window, pol, scale):
     o, _ = kops.flash_attention_fwd(
-        q, k, v, causal=causal, window=window, policy=pol)
+        q, k, v, causal=causal, window=window, policy=pol, scale=scale)
     return o
 
 
-def _attention_fused_fwd(q, k, v, causal, window, pol):
+def _attention_fused_fwd(q, k, v, causal, window, pol, scale):
     o, lse = kops.flash_attention_fwd(
-        q, k, v, causal=causal, window=window, policy=pol)
+        q, k, v, causal=causal, window=window, policy=pol, scale=scale)
     return o, (q, k, v, o, lse)
 
 
-def _attention_fused_bwd(causal, window, pol, res, do):
+def _attention_fused_bwd(causal, window, pol, scale, res, do):
     q, k, v, o, lse = res
     return kops.flash_attention_bwd(
-        q, k, v, o, do, lse, causal=causal, window=window, policy=pol)
+        q, k, v, o, do, lse, causal=causal, window=window, policy=pol,
+        scale=scale)
 
 
 _attention_fused.defvjp(_attention_fused_fwd, _attention_fused_bwd)
@@ -192,7 +194,8 @@ _attention_fused.defvjp(_attention_fused_fwd, _attention_fused_bwd)
 
 def attention(q, k, v, *, causal, window, chunk, q_offset=0, kv_len=None,
               policy: Policy | None = None, backend: str | None = None,
-              io_dtype=jnp.float32, decode: bool = False):
+              io_dtype=jnp.float32, decode: bool = False,
+              scale: Optional[float] = None):
     """The attention chokepoint (né `attend`). Routing under the
     resolved policy:
 
@@ -211,7 +214,8 @@ def attention(q, k, v, *, causal, window, chunk, q_offset=0, kv_len=None,
     can identify attention-interior traffic — on the TPU target this
     whole region is the Pallas flash kernel (same math, validated in
     interpret mode) whose intermediates never touch HBM. §Perf models
-    that substitution from the tag.
+    that substitution from the tag. `scale` is the softmax scale on
+    every path (None = D^-0.5).
     """
     pol = _route_dtype(_resolve_attn_policy(policy, backend), q.dtype)
     if pol.backend == "pallas":
@@ -219,20 +223,22 @@ def attention(q, k, v, *, causal, window, chunk, q_offset=0, kv_len=None,
             # kv_len = q_offset + 1 by the decode contract: the kernel's
             # per-row prefix mask IS causal masking at depth q_offset.
             return kops.flash_decode(
-                q, k, v, pos=q_offset, window=window, policy=pol)
+                q, k, v, pos=q_offset, window=window, policy=pol,
+                scale=scale)
         if kv_len is None and _flash_shapes_ok(q.shape[1], k.shape[1]) \
                 and isinstance(q_offset, int) and q_offset == 0:
-            return _attention_fused(q, k, v, causal, window, pol)
+            return _attention_fused(q, k, v, causal, window, pol, scale)
     elif pol.backend != "xla" and kv_len is None:
         # naive etc.: the forward-only op (registry raises for backends
         # with no flash impl, listing the registered ones)
         return kops.flash_attention(
             q, k, v, causal=causal, window=window, q_offset=q_offset,
-            policy=pol)
+            policy=pol, scale=scale)
     with jax.named_scope("flashsite"):
         return chunked_attention(
             q, k, v, causal=causal, window=window, chunk=chunk,
-            q_offset=q_offset, kv_len=kv_len, io_dtype=io_dtype)
+            q_offset=q_offset, kv_len=kv_len, io_dtype=io_dtype,
+            scale=scale)
 
 
 #: Backwards-compatible alias — attn_apply and external callers used
@@ -276,7 +282,7 @@ def _project_kv(p, x, cfg):
     k = constrain(k, "dp", None, "tp", None)   # kv heads stay head-sharded
     v = constrain(v, "dp", None, "tp", None)   # (or replicated if MQA-ish)
     if cfg.qk_norm:
-        k = L.rmsnorm_apply(p["k_norm"], k)
+        k = L.rmsnorm_apply(p["k_norm"], k, eps=cfg.norm_eps)
     return k, v
 
 
@@ -312,6 +318,8 @@ def attn_apply(
     and masked prefill-into-cache stays on the chunked XLA path (see
     attention())."""
     pol = _resolve_attn_policy(policy, backend)
+    # the configuration's softmax scale on every path (None = dh^-0.5)
+    attend = functools.partial(attention, scale=cfg.attention_multiplier)
     b, t, _ = x.shape
     dh = cfg.resolved_head_dim
     use_rope = cfg.use_rope if use_rope is None else use_rope
@@ -319,7 +327,7 @@ def attn_apply(
     q = L.dense_apply(p["wq"], x).reshape(b, t, cfg.n_heads, dh)
     q = _constrain_bthd(q, cfg)
     if cfg.qk_norm:
-        q = L.rmsnorm_apply(p["q_norm"], q)
+        q = L.rmsnorm_apply(p["q_norm"], q, eps=cfg.norm_eps)
 
     io_dtype = jnp.float32 if cfg.attn_f32_io else jnp.bfloat16
 

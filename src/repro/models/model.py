@@ -50,6 +50,8 @@ def init_params(cfg, key) -> Dict[str, Any]:
         p["layers"] = T.stack_init(ks[2], cfg)
     elif fam == "ssm":
         p["layers"] = T.ssm_stack_init(ks[2], cfg)
+    elif fam == "hybrid" and cfg.layer_types:
+        p["layers"] = T.interleaved_init(ks[2], cfg)
     elif fam == "hybrid":
         p["hybrid"] = T.hybrid_init(ks[2], cfg)
     elif fam == "encdec":
@@ -102,6 +104,8 @@ def quantize_params(params, *, spec=None, exclude=QUANT_EXCLUDE):
 def _embed_inputs(cfg, params, batch):
     dtype = jnp.dtype(cfg.dtype)
     x = L.embed_apply(params["embed"], batch["tokens"], dtype=dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if cfg.family == "vlm" and "patch_embeds" in batch:
         # Vision stub: precomputed patch embeddings arrive aligned with
         # the token grid (zeros at text positions) and are added in.
@@ -115,6 +119,8 @@ def _logits(cfg, params, x):
         logits = L.embed_attend(params["embed"], x)
     else:
         logits = L.dense_apply(params["lm_head"], x, out_dtype=jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.padded_vocab != cfg.vocab:
         # Megatron-style vocab padding: mask pad classes out of softmax.
         pad_mask = jnp.arange(cfg.padded_vocab) < cfg.vocab
@@ -141,6 +147,9 @@ def forward(cfg, params, batch) -> tuple[jnp.ndarray, dict]:
     elif fam == "ssm":
         x = _embed_inputs(cfg, params, batch)
         x, _ = T.ssm_stack_apply(params["layers"], x, cfg)
+    elif fam == "hybrid" and cfg.layer_types:
+        x = _embed_inputs(cfg, params, batch)
+        x, _ = T.interleaved_apply(params["layers"], x, cfg)
     elif fam == "hybrid":
         x = _embed_inputs(cfg, params, batch)
         x, _, _ = T.hybrid_apply(params["hybrid"], x, cfg, emb0=x)
@@ -192,6 +201,14 @@ def init_cache(cfg, batch: int, max_len: int, enc_len: int = 0):
         st = S.mamba_init_state(cfg, batch, dtype=dtype)
         return jax.tree.map(
             lambda a: jnp.broadcast_to(a, (cfg.n_layers,) + a.shape).copy(), st)
+    if fam == "hybrid" and cfg.layer_types:
+        st = S.mamba_init_state(cfg, batch, dtype=dtype)
+        n_mamba = cfg.layer_types.count("mamba")
+        return {"mamba": jax.tree.map(
+                    lambda a: jnp.broadcast_to(
+                        a, (n_mamba,) + a.shape).copy(), st),
+                "attn": kv(cfg.layer_types.count("attention"), max_len,
+                           cfg.n_kv_heads)}
     if fam == "hybrid":
         n_seg = cfg.n_layers // cfg.attn_every
         st = S.mamba_init_state(cfg, batch, dtype=dtype)
@@ -255,6 +272,10 @@ def prefill(cfg, params, batch, cache, pos: int = 0):
     elif fam == "ssm":
         x = _embed_inputs(cfg, params, batch)
         x, cache = T.ssm_stack_apply(params["layers"], x, cfg, states=cache)
+    elif fam == "hybrid" and cfg.layer_types:
+        x = _embed_inputs(cfg, params, batch)
+        x, cache = T.interleaved_apply(params["layers"], x, cfg,
+                                       caches=cache, cache_pos=pos)
     elif fam == "hybrid":
         x = _embed_inputs(cfg, params, batch)
         x, attn_c, mamba_c = T.hybrid_apply(
@@ -311,6 +332,11 @@ def decode_step(cfg, params, token, pos, cache):
         x = _embed_inputs(cfg, params, batch)
         x, cache = T.ssm_stack_apply(params["layers"], x, cfg,
                                      states=cache, decode=True)
+    elif fam == "hybrid" and cfg.layer_types:
+        x = _embed_inputs(cfg, params, batch)
+        x, cache = T.interleaved_apply(params["layers"], x, cfg,
+                                       caches=cache, cache_pos=pos,
+                                       decode=True)
     elif fam == "hybrid":
         x = _embed_inputs(cfg, params, batch)
         x, attn_c, mamba_c = T.hybrid_apply(

@@ -140,7 +140,7 @@ def mamba_apply(p, x, cfg, *, d_model=None, return_state: bool = False):
     y = y + p["D"][None, None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(bsz, l, d_inner).astype(x.dtype)
     y = constrain(y, "dp", None, "tp")
-    y = L.rmsnorm_apply(p["norm"], y * jax.nn.silu(z))
+    y = L.rmsnorm_apply(p["norm"], y * jax.nn.silu(z), eps=cfg.norm_eps)
     out = constrain(L.dense_apply(p["out_proj"], y), "dp", None, None)
     if not return_state:
         return out, None
@@ -208,6 +208,6 @@ def mamba_decode(p, x_t, cfg, state, *, d_model=None):
     y = jnp.einsum("bhn,bhpn->bhp", c_h, s)
     y = y + p["D"][None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(bsz, 1, d_inner).astype(x_t.dtype)
-    y = L.rmsnorm_apply(p["norm"], y * jax.nn.silu(z))
+    y = L.rmsnorm_apply(p["norm"], y * jax.nn.silu(z), eps=cfg.norm_eps)
     out = L.dense_apply(p["out_proj"], y)
     return out, {"ssd": s, "conv": new_conv, "conv_bc": new_conv_bc}

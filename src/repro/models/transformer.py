@@ -9,7 +9,9 @@ Families:
   ssm       — Mamba-2 stack (norm + mamba residual)
   hybrid    — Zamba2: Mamba-2 backbone + ONE weight-shared attention
               block invoked every `attn_every` layers with per-invocation
-              LoRA deltas and concat([hidden, embed0]) input
+              LoRA deltas and concat([hidden, embed0]) input; or, with
+              `layer_types` (Granite 4.0-H), Mamba-2 and attention layers
+              interleaved, each with its own weights and an MLP
   encdec    — Whisper: bidirectional encoder (stub conv frontend
               upstream) + causal decoder with cross-attention
 """
@@ -53,7 +55,7 @@ def _norm_init(cfg, d=None):
 def _norm_apply(cfg, p, x):
     if cfg.norm == "ln":
         return L.layernorm_apply(p, x)
-    return L.rmsnorm_apply(p, x)
+    return L.rmsnorm_apply(p, x, eps=cfg.norm_eps)
 
 
 # ----------------------------------------------------------------------
@@ -294,3 +296,120 @@ def hybrid_apply(params, x, cfg, *, emb0, attn_caches=None, cache_pos=None,
     seg_in = (params["mamba"], mamba_states, attn_caches, lora_xs)
     x, (new_states, new_caches) = jax.lax.scan(seg_body, x, seg_in)
     return x, new_caches, new_states
+
+
+# ----------------------------------------------------------------------
+# Interleaved hybrid stack (Granite 4.0-H)
+# ----------------------------------------------------------------------
+#
+# Layer i mixes with Mamba-2 or attention as cfg.layer_types[i] says, and
+# every layer follows its mixer with a SwiGLU MLP; both branches are
+# scaled by cfg.residual_multiplier before their add:
+#
+#     h += r * mixer_i(norm(h));    h += r * mlp_i(norm(h))
+#
+# Each kind's weights (and cache) are stacked over its own layers in
+# depth order. The stack scans over whole periods of layer_types; inside
+# a period each run of same-kind layers is a scan of its own, so the
+# compiled program holds one body per run, not one per layer. A layer
+# indexes its weights out of the stacks, and its cache too, which it
+# writes back in place: the stacked cache rides the loops' carry, so a
+# step holds no second copy of it.
+
+def _runs(kinds):
+    """(kind, first layer, length) of each run of equal kinds."""
+    out = []
+    for j, k in enumerate(kinds):
+        if out and out[-1][0] == k:
+            out[-1][2] += 1
+        else:
+            out.append([k, j, 1])
+    return [tuple(r) for r in out]
+
+
+def interleaved_init(key, cfg):
+    """{"mamba": {norm, mamba}, "attn": {norm, attn}, "mlp": {norm,
+    mlp}}, each stacked over the layers that have it."""
+    km, ka, kf = jax.random.split(key, 3)
+
+    def stacked(fn, k, count):
+        return jax.vmap(fn)(jax.random.split(k, count))
+
+    return {
+        "mamba": stacked(lambda k: {"norm": _norm_init(cfg),
+                                    "mamba": S.mamba_init(k, cfg)},
+                         km, cfg.layer_types.count("mamba")),
+        "attn": stacked(lambda k: {"norm": _norm_init(cfg),
+                                   "attn": A.attn_init(k, cfg)},
+                        ka, cfg.layer_types.count("attention")),
+        "mlp": stacked(lambda k: {"norm": _norm_init(cfg),
+                                  "mlp": F.mlp_init(k, cfg)},
+                       kf, cfg.n_layers),
+    }
+
+
+def _branch(cfg, x, h):
+    if cfg.residual_multiplier != 1.0:
+        h = h * cfg.residual_multiplier
+    return x + h
+
+
+def interleaved_apply(params, x, cfg, *, caches=None, cache_pos=None,
+                      decode=False):
+    """caches: {"mamba": stacked states (n_mamba, B, ...), "attn":
+    {"k", "v": (n_attn, B, Tmax, Hkv, Dh)}}, or None (training).
+    Returns (x, new_caches)."""
+    per = cfg.layer_period
+    kinds = cfg.layer_types[:per]
+    key = {"mamba": "mamba", "attention": "attn"}
+    count = {k: kinds.count(k) for k in key}
+    collect = caches is not None and not decode
+
+    def at(tree, i):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            tree)
+
+    def layer(kind, xc, lp, fp, st):
+        with jax.named_scope(f"repro.{key[kind]}"):
+            u = _norm_apply(cfg, lp["norm"], xc)
+            if kind == "attention":
+                h, st = A.attn_apply(lp["attn"], u, cfg, cache=st,
+                                     cache_pos=cache_pos)
+            elif decode:
+                h, st = S.mamba_decode(lp["mamba"], u, cfg, st)
+            else:
+                h, st = S.mamba_apply(lp["mamba"], u, cfg,
+                                      return_state=collect)
+        xc = _branch(cfg, xc, h)
+        xc = _branch(cfg, xc, F.mlp_apply(
+            fp["mlp"], _norm_apply(cfg, fp["norm"], xc), cfg))
+        return constrain(xc, "dp", None, None), st
+
+    def run(kind, first_kind, first_layer):
+        k = key[kind]
+
+        def body(carry, r):
+            xc, cache = carry
+            i = first_kind + r
+            st = None if cache is None else at(cache[k], i)
+            xc, st = layer(kind, xc, at(params[k], i),
+                           at(params["mlp"], first_layer + r), st)
+            if cache is not None:
+                cache = {**cache, k: jax.tree.map(
+                    lambda c, s: jax.lax.dynamic_update_index_in_dim(
+                        c, s.astype(c.dtype), i, 0), cache[k], st)}
+            return (xc, cache), None
+        return body if decode else _maybe_remat(body, cfg)
+
+    def period(carry, p):
+        done = {k: 0 for k in key}
+        for kind, j0, n in _runs(kinds):
+            body = run(kind, p * count[kind] + done[kind], p * per + j0)
+            carry, _ = jax.lax.scan(body, carry, jnp.arange(n))
+            done[kind] += n
+        return carry, None
+
+    (x, caches), _ = jax.lax.scan(period, (x, caches),
+                                  jnp.arange(cfg.n_layers // per))
+    return x, caches

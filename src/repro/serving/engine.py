@@ -34,7 +34,11 @@ same stamps as the admit and decode spans.
 
 Family notes: attention caches copy per-slot KV rows; ssm/hybrid copy
 recurrent state rows (their "position" is implicit in the state, the
-pos vector only drives the attention members and bookkeeping). MoE is
+pos vector only drives the attention members and bookkeeping); an
+interleaved hybrid (Granite 4.0-H) copies both, each leaf along its own
+slot axis. The admit and slot-copy spans carry the slot's `state_bytes`
+(recurrent state) and `kv_bytes` (its KV row), the decode span the
+`state_bytes` of every slot's state, which each step reads and writes. MoE is
 served but not token-exact vs. an isolated run by construction: expert
 capacity is contended by whichever tokens share the decode batch.
 
@@ -135,6 +139,18 @@ def _slot_axis(big_shape, small_shape, name: str = "cache leaf"):
     return diffs[0]
 
 
+def _slot_bytes(small) -> Dict[str, int]:
+    """Bytes of one slot's cache by kind: `kv_bytes` (its K/V leaves)
+    and `state_bytes` (every other leaf: recurrent state), from the
+    shapes of a 1-slot cache."""
+    out = {"state_bytes": 0, "kv_bytes": 0}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(small)[0]:
+        kind = "kv_bytes" if getattr(path[-1], "key", None) in ("k", "v") \
+            else "state_bytes"
+        out[kind] += leaf.size * leaf.dtype.itemsize
+    return out
+
+
 class ServingEngine:
     def __init__(self, cfg, params, *, max_slots: int, max_len: int,
                  sampler: Optional[Sampler] = None,
@@ -225,6 +241,8 @@ class ServingEngine:
                            name=jax.tree_util.keystr(path))
                 for (path, b), s in zip(flat, jax.tree.leaves(small))]
             self._write = jax.jit(self._write_slot, donate_argnums=(0,))
+        self._slot_bytes = _slot_bytes(jax.eval_shape(
+            lambda: M.init_cache(cfg, 1, self.max_len)))
 
         # -- speculative decoding (serving.spec) ------------------------
         # draft=(draft_cfg, draft_params) turns every decode step into a
@@ -446,7 +464,8 @@ class ServingEngine:
         chunk = self.prefill_chunk
         lb = L - (L % chunk) or L      # bucket down; short prompts exact
         with spans.timed("repro.engine.admit", rid=req.rid, prompt_len=L,
-                         bucket=lb, token_steps=L - lb) as adm:
+                         bucket=lb, token_steps=L - lb,
+                         **self._slot_bytes) as adm:
             with spans.span("repro.engine.admit.prefill"):
                 batch: Dict[str, Any] = {
                     "tokens": jnp.asarray(ctx[None, :lb])}
@@ -459,7 +478,8 @@ class ServingEngine:
                     logits, sub = self._admit_step(
                         self.params, jnp.asarray(ctx[None, None, i]),
                         jnp.int32(i), sub)
-            with spans.span("repro.engine.admit.slot_copy"):
+            with spans.span("repro.engine.admit.slot_copy",
+                            **self._slot_bytes):
                 self._copy_prefill(slot, sub, plan)
             with spans.span("repro.engine.admit.first_token"):
                 row = np.asarray(logits)[0, -1, :self.cfg.vocab]
@@ -784,20 +804,28 @@ class ServingEngine:
 
     # -- driving -------------------------------------------------------
     def step(self) -> bool:
-        """Drop expired waiters, admit every ready request (preempting
-        for a pool-starved FCFS head when a victim exists), then run one
-        decode step if any slot is active. Returns False when all work
-        is drained."""
+        """Drop expired waiters, admit ready requests (preempting for a
+        pool-starved FCFS head when a victim exists), then run one
+        decode step if any slot is active. An admission stalls every
+        decoding slot, so while one decodes a step admits at most one
+        request and no token gap spans two admissions; with none
+        decoding it admits every ready request. Returns False when all
+        work is drained."""
         with spans.span("repro.engine.step"):
+            decoding = self.scheduler.n_active > 0
             while True:
                 with spans.span("repro.engine.schedule"):
                     req = self._next_admission()
                 if req is None:
                     break
                 self._admit(req)
+                if decoding:
+                    break
             if self.scheduler.n_active:
                 with spans.span("repro.engine.decode", step=self.decode_steps,
-                                active=self.scheduler.n_active):
+                                active=self.scheduler.n_active,
+                                state_bytes=self.max_slots
+                                * self._slot_bytes["state_bytes"]):
                     if self.spec is not None:
                         self._spec_decode_once()
                     else:

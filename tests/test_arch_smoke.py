@@ -137,6 +137,7 @@ def test_param_counts_full_configs():
         "arctic-480b": (400e9, 520e9),
         "mamba2-2.7b": (2.2e9, 3.2e9),
         "zamba2-1.2b": (0.9e9, 1.7e9),
+        "granite-4.0-h-micro": (3.0e9, 3.4e9),
         "whisper-tiny": (25e6, 80e6),
         "qwen2-vl-2b": (1.2e9, 2.4e9),
     }

@@ -23,7 +23,11 @@ ASSIGNED = [
     ("granite-3-8b", "dense", 40, 4096, 32, 8, 12800, 49155, {}),
     ("zamba2-1.2b", "hybrid", 36, 2048, 32, 32, 8192, 32000,
      dict(attn_every=6)),
-    ("mamba2-2.7b", "ssm", 64, 2560, 1, 1, 0, 50280, {}),
+    ("mamba2-2.7b", "ssm", 64, 2560, 1, 1, 0, 50280, dict(norm_eps=1e-5)),
+    ("granite-4.0-h-micro", "hybrid", 40, 2048, 32, 8, 8192, 100352,
+     dict(head_dim=64, use_rope=False, tie_embeddings=True, norm_eps=1e-5,
+          embedding_multiplier=12.0, attention_multiplier=0.015625,
+          residual_multiplier=0.22, logits_scaling=8.0, layer_period=10)),
 ]
 
 
@@ -54,6 +58,20 @@ def test_moe_ssm_extras():
     mam = C.get_config("mamba2-2.7b").ssm
     assert mam.d_state == 128
     assert C.get_config("zamba2-1.2b").shared_attn_lora_rank > 0
+    gr = C.get_config("granite-4.0-h-micro")
+    assert (gr.ssm.d_state, gr.ssm.head_dim, gr.ssm.expand, gr.ssm.chunk,
+            gr.ssm.n_groups, gr.ssm.conv_width) == (128, 64, 2, 256, 1, 4)
+    # published layer_types: attention at 5, 15, 25, 35, Mamba-2 elsewhere
+    assert [i for i, t in enumerate(gr.layer_types)
+            if t == "attention"] == [5, 15, 25, 35]
+    assert gr.layer_types.count("mamba") == 36
+
+
+def test_reduced_interleaved_keeps_both_layer_kinds():
+    red = C.get_config("granite-4.0-h-micro", reduced=True)
+    assert set(red.layer_types) == {"mamba", "attention"}
+    assert len(red.layer_types) == red.n_layers
+    assert red.n_layers // red.layer_period >= 2     # the period scan runs
 
 
 def test_every_arch_has_reduced():
@@ -76,10 +94,13 @@ def test_shape_cells():
 
 
 def test_long500k_applicability_table():
-    """DESIGN §6: exactly mamba2/zamba2/mixtral run long_500k."""
+    """DESIGN §6: exactly the ssm/hybrid families and windowed mixtral
+    run long_500k (granite-4.0-h-micro is a hybrid: 36 of its 40 layers
+    hold constant-size state)."""
     from repro.configs.base import get_shape
     from repro.launch import specs as S
     cell = get_shape("long_500k")
     runs = {n for n in C.ARCH_NAMES
             if S.applicable(C.get_config(n), cell)[0]}
-    assert runs == {"mamba2-2.7b", "zamba2-1.2b", "mixtral-8x22b"}
+    assert runs == {"mamba2-2.7b", "zamba2-1.2b", "mixtral-8x22b",
+                    "granite-4.0-h-micro"}

@@ -12,6 +12,8 @@ whichever tokens share a decode batch, so continuous batching is not
 token-exact vs an isolated run by construction (see serving/engine.py).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,7 +23,7 @@ from repro.configs import get_config
 from repro.models import model as M
 from repro.serving import SamplerConfig, ServingEngine, SlotScheduler, \
     make_sampler
-from repro.serving.request import Request
+from repro.serving.request import ACTIVE, WAITING, Request
 
 
 def _reference_generate(cfg, params, prompt, n_new, enc=None):
@@ -265,6 +267,32 @@ def test_scheduler_fcfs_and_release():
     sched.release(reqs[0].slot)
     assert sched.next_admission(now=5.0) is reqs[2]
     assert sched.n_free == 1 and sched.n_waiting == 1 and sched.n_active == 1
+
+
+def test_engine_admits_one_request_per_step_while_a_slot_decodes():
+    """With no slot decoding a step admits every ready request; once one
+    decodes, a step admits one and decodes, so that no token gap spans
+    two admissions. The tokens stay those of an isolated run (float32,
+    so that no near-tie turns on the batch's shape)."""
+    cfg = dataclasses.replace(get_config("qwen3-0.6b", reduced=True),
+                              dtype="float32")
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, max_slots=4, max_len=64)
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, cfg.vocab, (n,)).astype(np.int32)
+               for n in (7, 9, 11, 5)]
+    r = [eng.submit(prompts[i], 6) for i in range(2)]
+    eng.step()
+    assert [q.status for q in r] == [ACTIVE] * 2
+    r += [eng.submit(prompts[i], 6) for i in (2, 3)]
+    eng.step()
+    assert [q.status for q in r] == [ACTIVE] * 3 + [WAITING]
+    assert [len(q.generated) for q in r] == [3, 3, 2, 0]
+    eng.step()
+    assert [len(q.generated) for q in r] == [4, 4, 3, 2]
+    eng.run()
+    for q, prompt in zip(r, prompts):
+        assert q.generated == _reference_generate(cfg, params, prompt, 6)
 
 
 def test_engine_rounds_max_len_to_attn_chunk():
